@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.models import build_model
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import ServeEngine, Scheduler
 
 
@@ -49,6 +50,7 @@ def main():
     ap.add_argument("--gamma-max", type=int, default=4,
                     help="speculation: max draft tokens per round")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     model = build_model(cfg)

@@ -19,6 +19,7 @@ from repro.core import DiagnosticConfig, SimplifiedDelayModel, StrategyConfig
 from repro.data import StagedBatcher, TokenStream
 from repro.models import build_model
 from repro.optim.optimizers import get_optimizer
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.train_loop import TrainLoopConfig, train
 
 
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--fail-worker-at", type=int, default=None,
                     help="inject a worker failure at this step")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.preset == "smollm":
         cfg = get_config("smollm-135m")

@@ -20,21 +20,6 @@ as weighted reductions and sharding constraints so GSPMD chooses the
 actual all-reduce/all-gather schedule.
 """
 
-import jax
-
-if not hasattr(jax, "set_mesh"):
-    # Compatibility shim for older jax (< 0.5): launch scripts and tests
-    # use ``with jax.set_mesh(mesh):`` from the newer API. A ``Mesh`` is
-    # itself a context manager that installs the ambient mesh, so the
-    # shim simply returns it. Caveat: only the context-manager usage is
-    # emulated — a bare ``jax.set_mesh(mesh)`` statement does NOT install
-    # a global mesh the way the real API does. Self-disables once jax
-    # provides the real function.
-    def _set_mesh(mesh):
-        return mesh
-
-    jax.set_mesh = _set_mesh
-
 from .collectives import contributors, example_weights, masked_weighted_ce
 from .compression import Int8Codec, ef_compress_tree
 from .sharding import (

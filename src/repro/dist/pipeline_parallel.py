@@ -18,7 +18,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["stage_params", "pipeline_forward"]
@@ -110,11 +109,11 @@ def pipeline_forward(
         # replicates them so the caller sees one full array.
         return jax.lax.psum(outs, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(staged_params, x)

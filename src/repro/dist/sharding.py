@@ -38,6 +38,7 @@ __all__ = [
     "logical_to_pspec",
     "batch_pspec",
     "make_sharding_fn",
+    "abstract_state",
     "activation_sharding",
     "constrain_batch",
     "constrain_logical",
@@ -178,6 +179,47 @@ def make_sharding_fn(
         )
 
     return sharding_for
+
+
+def abstract_state(model, mesh, rules: ShardingRules, optimizer=None):
+    """Abstract (params, opt_state) with production shardings attached.
+    ``model`` is anything with ``abstract_params(sharding_fn)``."""
+    params = model.abstract_params(make_sharding_fn(mesh, rules))
+    if optimizer is None:
+        return params, None
+    opt_state = jax.eval_shape(optimizer.init, params)
+
+    # eval_shape loses shardings; attach by matching shapes against params.
+    # Exact-shape matches cover adam m/v; adafactor factored rows
+    # (p.shape[:-1]) and cols (p.shape[:-2] + p.shape[-1:]) inherit the
+    # param's pspec with the corresponding dim removed.
+    param_leaves = jax.tree.leaves(params)
+    by_shape = {}
+    row_shapes = {}
+    col_shapes = {}
+    for p in param_leaves:
+        by_shape.setdefault(p.shape, p.sharding)
+        spec = tuple(p.sharding.spec) + (None,) * (len(p.shape) - len(p.sharding.spec))
+        if len(p.shape) >= 2:
+            row_shapes.setdefault(p.shape[:-1], P(*spec[:-1]))
+            col_shapes.setdefault(
+                p.shape[:-2] + p.shape[-1:], P(*(spec[:-2] + spec[-1:]))
+            )
+
+    def attach(x):
+        if not hasattr(x, "shape"):
+            return x
+        sh = by_shape.get(x.shape)
+        if sh is None and x.shape in row_shapes:
+            sh = NamedSharding(mesh, row_shapes[x.shape])
+        if sh is None and x.shape in col_shapes:
+            sh = NamedSharding(mesh, col_shapes[x.shape])
+        if sh is None:
+            sh = NamedSharding(mesh, P())
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh)
+
+    opt_state = jax.tree.map(attach, opt_state)
+    return params, opt_state
 
 
 # ---------------------------------------------------------------------------

@@ -5,11 +5,18 @@ every decode cell): the step reads the whole KV cache once. This kernel
 streams the cache HBM->VMEM in blocks on the LAST (sequential) grid dim,
 carrying partial softmax statistics (m, l, acc) in VMEM scratch, and
 masks beyond the valid length — one pass, no (S,) score materialization
-in HBM, MXU-shaped (G x block_kv) @ (block_kv x D) products.
+in HBM.
 
-Grid = (B, Hkv, num_kv_blocks); each program owns one (batch, kv-head)
-pair and reduces over its query GROUP (GQA: G = H / Hkv queries share a
-kv head) so the cache block is read once for all G queries.
+Grid = (B, num_kv_blocks); each program owns one sequence and reads each
+cache block ONCE for every head. The cache's (Hkv, D) minor dims are
+viewed as one (Hkv * D) row (a free reshape), so a block is a
+(block_kv, Hkv * D) slab whose last two dims the TPU tiles natively. GQA
+is resolved with a block-diagonal query: head ``j``'s query occupies the
+``D`` columns of its kv head ``j // G`` and is zero elsewhere, so one
+(H, Hkv*D) @ (Hkv*D, block_kv) product yields every head's scores and
+one (H, block_kv) @ (block_kv, Hkv*D) product every head's partial
+output (the wrapper keeps each head's own kv-head columns). Decode is
+bandwidth-bound, so the Hkv-fold extra MXU work is free.
 """
 
 from __future__ import annotations
@@ -27,48 +34,90 @@ __all__ = ["decode_attention_fwd", "paged_decode_attention_fwd"]
 NEG_INF = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_ref, l_ref, acc_ref,
+def _block_diag_query(q: jax.Array, Hkv: int) -> jax.Array:
+    """(B, H, D) -> (B, H, Hkv * D): head j's query in the columns of its
+    kv head j // G, zeros elsewhere."""
+    B, H, D = q.shape
+    owner = jnp.arange(H) // (H // Hkv)                          # (H,)
+    sel = (owner[:, None] == jnp.arange(Hkv)[None, :]).astype(q.dtype)
+    return (q[:, :, None, :] * sel[None, :, :, None]).reshape(B, H, Hkv * D)
+
+
+def _own_head_columns(out: jax.Array, Hkv: int, Dv: int) -> jax.Array:
+    """(B, H, Hkv * Dv) -> (B, H, Dv): each head's own kv-head block."""
+    B, H, _ = out.shape
+    owner = jnp.arange(H) // (H // Hkv)
+    out = out.reshape(B, H, Hkv, Dv)
+    return jnp.take_along_axis(out, owner[None, :, None, None], axis=2)[:, :, 0]
+
+
+def _online_softmax_step(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
+                         scale, kv_start, length):
+    """One cache block of the flash-decode recurrence for every head."""
+    q = q_ref[0].astype(jnp.float32) * scale                # (H, Hkv*D)
+    k = k_ref[0].astype(jnp.float32)                        # (bkv, Hkv*D)
+    v = v_ref[0].astype(jnp.float32)                        # (bkv, Hkv*Dv)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )                                                       # (H, bkv)
+    kv_ids = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(kv_ids < length, s, NEG_INF)
+
+    m_prev = m_ref[...]                                     # (H, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )                                                       # (H, Hkv*Dv)
+    acc_ref[...] = acc_ref[...] * corr + pv
+    m_ref[...] = m_new
+
+
+def _init_stats(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _write_out(o_ref, l_ref, acc_ref):
+    # length == 0 leaves l at 0 -> output exactly zeros (the paged
+    # oracle mirrors this convention for empty sequences).
+    l = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
             *, scale, block_kv):
-    ikv = pl.program_id(2)
-    n_kv = pl.num_programs(2)
+    b = pl.program_id(0)
+    ikv = pl.program_id(1)
 
     @pl.when(ikv == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_stats(m_ref, l_ref, acc_ref)
 
-    length = len_ref[0, 0]
+    length = len_ref[b]
     kv_start = ikv * block_kv
 
     @pl.when(kv_start < length)
     def _step():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale    # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bkv, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)            # (bkv, Dv)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                     # (G, bkv)
-        kv_ids = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kv_ids < length, s, NEG_INF)
+        _online_softmax_step(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                             scale=scale, kv_start=kv_start, length=length)
 
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:, 0] = l_ref[:, 0] * corr + p.sum(axis=1)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-        m_ref[:, 0] = m_new
-
-    @pl.when(ikv == n_kv - 1)
+    @pl.when(ikv == pl.num_programs(1) - 1)
     def _finish():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        _write_out(o_ref, l_ref, acc_ref)
+
+
+def _scratch(H: int, width: int):
+    return [
+        pltpu.VMEM((H, 1), jnp.float32),       # m
+        pltpu.VMEM((H, 1), jnp.float32),       # l
+        pltpu.VMEM((H, width), jnp.float32),   # acc
+    ]
 
 
 def decode_attention_fwd(
@@ -82,7 +131,6 @@ def decode_attention_fwd(
 ) -> jax.Array:
     B, H, D = q.shape
     S, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
-    G = H // Hkv
     scale = 1.0 / math.sqrt(D)
 
     block_kv = min(block_kv, S)
@@ -101,29 +149,29 @@ def decode_attention_fwd(
             )
     n_kv = S // block_kv
 
-    # Group queries by kv head: (B, Hkv, G, D).
-    qg = q.reshape(B, Hkv, G, D)
-    lengths = lengths.astype(jnp.int32).reshape(B, 1)
-
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # lengths
+        grid=(B, n_kv),
+        in_specs=[
+            pl.BlockSpec((1, H, Hkv * D), lambda b, i, lens: (b, 0, 0)),
+            pl.BlockSpec((1, block_kv, Hkv * D), lambda b, i, lens: (b, i, 0)),
+            pl.BlockSpec((1, block_kv, Hkv * Dv), lambda b, i, lens: (b, i, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, H, Hkv * Dv), lambda b, i, lens: (b, 0, 0)),
+        scratch_shapes=_scratch(H, Hkv * Dv),
+    )
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, block_kv=block_kv),
-        grid=(B, Hkv, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ikv: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_kv, 1, D), lambda b, h, ikv: (b, ikv, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, Dv), lambda b, h, ikv: (b, ikv, h, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, ikv: (b, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, Dv), lambda b, h, ikv: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, Dv), jnp.float32),
-        ],
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, Hkv * Dv), q.dtype),
         interpret=interpret,
-    )(qg, k, v, lengths)
-    return out.reshape(B, H, Dv)
+    )(
+        lengths.astype(jnp.int32),
+        _block_diag_query(q, Hkv),
+        k.reshape(B, S, Hkv * D),
+        v.reshape(B, S, Hkv * Dv),
+    )
+    return _own_head_columns(out, Hkv, Dv)
 
 
 # ---------------------------------------------------------------------------
@@ -140,48 +188,23 @@ def decode_attention_fwd(
 def _paged_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, scale, block_size):
     b = pl.program_id(0)
-    t = pl.program_id(2)
-    n_t = pl.num_programs(2)
+    t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_stats(m_ref, l_ref, acc_ref)
 
     length = len_ref[b]
     kv_start = t * block_size
 
     @pl.when(kv_start < length)
     def _step():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale    # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bs, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)            # (bs, Dv)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                     # (G, bs)
-        kv_ids = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kv_ids < length, s, NEG_INF)
+        _online_softmax_step(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                             scale=scale, kv_start=kv_start, length=length)
 
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:, 0] = l_ref[:, 0] * corr + p.sum(axis=1)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-        m_ref[:, 0] = m_new
-
-    @pl.when(t == n_t - 1)
+    @pl.when(t == pl.num_programs(1) - 1)
     def _finish():
-        # length == 0 leaves l at 0 -> output exactly zeros (the paged
-        # oracle mirrors this convention for empty sequences).
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        _write_out(o_ref, l_ref, acc_ref)
 
 
 def paged_decode_attention_fwd(
@@ -194,42 +217,40 @@ def paged_decode_attention_fwd(
     interpret: bool = False,
 ) -> jax.Array:
     B, H, D = q.shape
-    block_size, Hkv, Dv = k_arena.shape[1], k_arena.shape[2], v_arena.shape[3]
+    n_rows, block_size, Hkv, Dv = (k_arena.shape[0], k_arena.shape[1],
+                                   k_arena.shape[2], v_arena.shape[3])
     T = block_tables.shape[1]
-    G = H // Hkv
     scale = 1.0 / math.sqrt(D)
 
-    qg = q.reshape(B, Hkv, G, D)
-    block_tables = block_tables.astype(jnp.int32)
-    lengths = lengths.astype(jnp.int32)
-
-    def kv_map(b, h, t, tab_ref, len_ref):
+    def kv_map(b, t, tab_ref, len_ref):
         # Clamp dead table slots to the last live block: a repeated block
         # index costs no new copy, and the body skips the compute.
         n_live = jax.lax.div(len_ref[b] + block_size - 1, block_size)
         t_eff = jnp.minimum(t, jnp.maximum(n_live - 1, 0))
-        return (tab_ref[b, t_eff], 0, h, 0)
+        return (tab_ref[b, t_eff], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_tables, lengths
-        grid=(B, Hkv, T),
+        grid=(B, T),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, t, tab, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_size, 1, D), kv_map),
-            pl.BlockSpec((1, block_size, 1, Dv), kv_map),
+            pl.BlockSpec((1, H, Hkv * D), lambda b, t, tab, lens: (b, 0, 0)),
+            pl.BlockSpec((1, block_size, Hkv * D), kv_map),
+            pl.BlockSpec((1, block_size, Hkv * Dv), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, Dv), lambda b, h, t, tab, lens: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, Dv), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, H, Hkv * Dv),
+                               lambda b, t, tab, lens: (b, 0, 0)),
+        scratch_shapes=_scratch(H, Hkv * Dv),
     )
-
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, block_size=block_size),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Hkv * Dv), q.dtype),
         interpret=interpret,
-    )(block_tables, lengths, qg, k_arena, v_arena)
-    return out.reshape(B, H, Dv)
+    )(
+        block_tables.astype(jnp.int32),
+        lengths.astype(jnp.int32),
+        _block_diag_query(q, Hkv),
+        k_arena.reshape(n_rows, block_size, Hkv * D),
+        v_arena.reshape(n_rows, block_size, Hkv * Dv),
+    )
+    return _own_head_columns(out, Hkv, Dv)

@@ -1,6 +1,8 @@
 """Pallas TPU flash attention (GQA, causal) — pl.pallas_call + BlockSpec.
 
 TPU-native design (not a CUDA port):
+  * the wrapper moves heads ahead of the sequence, (B, H, S, D), so each
+    tile is one head's (rows x D) slab;
   * grid = (B, H, num_q_blocks, num_kv_blocks); the LAST grid dim is
     sequential on TPU, so the online-softmax state (m, l, acc) lives in
     VMEM scratch carried across kv steps of one (b, h, iq) tile;
@@ -45,9 +47,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale,
     kv_start = ikv * block_kv
 
     def _step():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale   # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (bkv, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)           # (bkv, Dv)
+        q = q_ref[0, 0].astype(jnp.float32) * scale          # (bq, D)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (bkv, D)
+        v = v_ref[0, 0].astype(jnp.float32)                  # (bkv, Dv)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -60,18 +62,18 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale,
             mask = mask & (q_ids >= kv_ids)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        # Softmax statistics stay 2-D (bq, 1): sublane-major columns.
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[:, 0] = l_prev * corr + p.sum(axis=1)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        acc_ref[...] = acc_ref[...] * corr[:, None] + pv
-        m_ref[:, 0] = m_new
+        acc_ref[...] = acc_ref[...] * corr + pv
+        m_ref[...] = m_new
 
     if causal:
         # Skip tiles strictly above the causal frontier (work elided,
@@ -82,8 +84,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale,
 
     @pl.when(ikv == n_kv - 1)
     def _finish():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_fwd(
@@ -113,6 +115,13 @@ def flash_attention_fwd(
     Sq_p, Skv_p = Sq + pad_q, Skv + pad_kv
     n_q, n_kv = Sq_p // block_q, Skv_p // block_kv
 
+    # Heads ahead of the sequence: every block's last two dims are then
+    # (rows, D) with D the full head dim, the layout the TPU tiles in
+    # (8, 128) sublane x lane units. A (1, rows, 1, D) block over the
+    # (B, S, H, D) layout would put a size-1 block on the second-minor
+    # axis, which the chip's compiler refuses.
+    q, k, v = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
+
     grid = (B, H, n_q, n_kv)
     kernel = functools.partial(
         _kernel,
@@ -127,26 +136,24 @@ def flash_attention_fwd(
         grid=grid,
         in_specs=[
             pl.BlockSpec(
-                (1, block_q, 1, D), lambda b, h, iq, ikv: (b, iq, h, 0)
+                (1, 1, block_q, D), lambda b, h, iq, ikv: (b, h, iq, 0)
             ),
             pl.BlockSpec(
-                (1, block_kv, 1, D), lambda b, h, iq, ikv, G=G: (b, ikv, h // G, 0)
+                (1, 1, block_kv, D), lambda b, h, iq, ikv, G=G: (b, h // G, ikv, 0)
             ),
             pl.BlockSpec(
-                (1, block_kv, 1, Dv), lambda b, h, iq, ikv, G=G: (b, ikv, h // G, 0)
+                (1, 1, block_kv, Dv), lambda b, h, iq, ikv, G=G: (b, h // G, ikv, 0)
             ),
         ],
         out_specs=pl.BlockSpec(
-            (1, block_q, 1, Dv), lambda b, h, iq, ikv: (b, iq, h, 0)
+            (1, 1, block_q, Dv), lambda b, h, iq, ikv: (b, h, iq, 0)
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Sq_p, H, Dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq_p, Dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),    # m (2-D for lanes)
+            pltpu.VMEM((block_q, 1), jnp.float32),    # m
             pltpu.VMEM((block_q, 1), jnp.float32),    # l
             pltpu.VMEM((block_q, Dv), jnp.float32),   # acc
         ],
         interpret=interpret,
     )(q, k, v)
-    if pad_q:
-        out = out[:, :Sq]
-    return out
+    return jnp.swapaxes(out, 1, 2)[:, :Sq]

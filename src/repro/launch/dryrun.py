@@ -37,12 +37,12 @@ from repro.dist.sharding import (
     PURE_DP_RULES,
     SP_DECODE_RULES,
     ShardingRules,
+    abstract_state,
     activation_sharding,
     make_sharding_fn,
 )
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import (
-    abstract_state,
     decode_input_specs,
     prefill_input_specs,
     train_input_specs,
@@ -238,8 +238,6 @@ def _finish(cfg, shape, mesh, rules, variant, cell_id, mesh_name, compiled,
     shape_name = shape.name
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-        cost = cost[0] if cost else None
     hlo = compiled.as_text()
     loop_cost = analyze_hlo(hlo)  # loop-aware (XLA counts while bodies once)
 

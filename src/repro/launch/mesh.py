@@ -8,8 +8,9 @@ must set XLA_FLAGS before any jax call).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_test_mesh"]
+__all__ = ["make_mesh", "make_production_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,10 +18,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2x16x16 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_test_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for CPU integration tests (requires
-    --xla_force_host_platform_device_count >= prod(shape))."""
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, devices=None):
+    """A mesh whose axes are all ``Auto``: the compiler propagates
+    shardings through gathers and reshapes. (``jax.make_mesh`` defaults
+    to ``Explicit`` axes, under which every such op must state its output
+    sharding.) ``devices`` defaults to ``jax.devices()``; on CPU, force
+    enough of them with --xla_force_host_platform_device_count."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )

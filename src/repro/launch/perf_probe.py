@@ -30,10 +30,9 @@ def main():
 
     from repro.analysis.hlo_cost import analyze_hlo
     from repro.configs import SHAPES, get_config
-    from repro.dist.sharding import activation_sharding
+    from repro.dist.sharding import abstract_state, activation_sharding
     from repro.launch.mesh import make_production_mesh
     from repro.launch.specs import (
-        abstract_state,
         decode_input_specs,
         prefill_input_specs,
         train_input_specs,

@@ -322,12 +322,12 @@ def gqa_apply(
     if cache is None:
         causal = cfg.causal and not cfg.is_encoder
         if cfg.use_pallas:
-            # TPU hot path: the Pallas flash kernel (interpret=True turns
-            # it into a CPU-executable reference for tests/dev boxes).
+            # The Pallas flash kernel compiles for the TPU only; on any
+            # other backend the call raises rather than quietly running
+            # the kernel's interpreter.
             from repro.kernels.flash_attention import flash_attention
 
-            interpret = jax.default_backend() != "tpu"
-            out = flash_attention(q, k, v, causal=causal, interpret=interpret)
+            out = flash_attention(q, k, v, causal=causal)
         else:
             out = mea_attention(q, k, v, causal=causal, chunk=cfg.attn_chunk)
         new_cache = None

@@ -28,6 +28,7 @@ from pathlib import Path
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["CheckpointError", "CheckpointManager", "CHECKPOINT_SCHEMA"]
@@ -88,8 +89,11 @@ class CheckpointManager:
             shutil.rmtree(tmp)
         tmp.mkdir(parents=True)
         np.savez(tmp / "arrays.npz", **arrays)
+        # npz keeps only numpy's own dtypes: a bfloat16 leaf reads back as
+        # raw 2-byte voids. The meta records every leaf's dtype by name.
         meta = {"step": step, "time": time.time(),
-                "schema": CHECKPOINT_SCHEMA, "extras": extras or {}}
+                "schema": CHECKPOINT_SCHEMA, "extras": extras or {},
+                "dtypes": {k: str(a.dtype) for k, a in arrays.items()}}
         (tmp / "meta.json").write_text(json.dumps(meta))
         # Durability order: file contents -> tmp dir entries -> atomic
         # rename -> parent dir entry (the rename itself) -> LATEST.
@@ -182,6 +186,7 @@ class CheckpointManager:
                 "matching build instead of guessing at the layout"
             )
 
+        dtypes = meta.get("dtypes", {})
         leaves_with_paths = jax.tree_util.tree_flatten_with_path(like)[0]
         treedef = jax.tree_util.tree_structure(like)
         out = []
@@ -190,6 +195,10 @@ class CheckpointManager:
             if key not in arrays:
                 raise KeyError(f"checkpoint missing leaf {key}")
             val = arrays[key]
+            if key in dtypes:
+                val = val.view(jnp.dtype(dtypes[key]))
+            if hasattr(leaf, "dtype"):
+                val = val.astype(leaf.dtype, copy=False)
             if device_put_fn is not None:
                 val = device_put_fn(val, leaf)
             out.append(val)

@@ -36,12 +36,18 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.controller import Controller, Stage, StrategyConfig
 from repro.core.order_stats import DelayModel
 from repro.data.pipeline import StagedBatcher
 from repro.dist.collectives import check_worker_major
-from repro.dist.sharding import activation_sharding
+from repro.dist.sharding import (
+    DEFAULT_RULES,
+    abstract_state,
+    activation_sharding,
+    batch_pspec,
+)
 from repro.models.model import Model
 from repro.obs import NULL_OBS, Observability
 from repro.optim.optimizers import Optimizer
@@ -76,6 +82,39 @@ def _event_schedule(cfg: TrainLoopConfig) -> Dict[int, List[FaultEvent]]:
     if cfg.fail_worker_at is not None:
         events.append(FaultEvent(cfg.fail_worker_at, "fail", cfg.fail_worker_id))
     return schedule_by_step(events)
+
+
+def _init_state(model: Model, optimizer: Optimizer, seed: int, mesh):
+    """Fresh (params, opt_state). With a mesh, each leaf is created
+    already sharded by ``DEFAULT_RULES`` (FSDP over ``data``, tensor
+    parallel over ``model``), so no device ever holds the whole model."""
+    key = jax.random.PRNGKey(seed)
+    if mesh is None:
+        params = model.init(key)
+        return params, optimizer.init(params)
+    params_abs, opt_abs = abstract_state(model, mesh, DEFAULT_RULES, optimizer)
+
+    def shardings(tree):
+        return jax.tree.map(lambda s: s.sharding, tree)
+
+    params = jax.jit(model.init, out_shardings=shardings(params_abs))(key)
+    opt_state = jax.jit(optimizer.init, out_shardings=shardings(opt_abs))(params)
+    return params, opt_state
+
+
+def _place_batch(batch: Dict[str, np.ndarray], mesh) -> Dict[str, jax.Array]:
+    """Host batch -> device. With a mesh the worker-major rows shard over
+    the data-parallel axes (each device holds whole workers' examples);
+    the worker mask and the learning rate are replicated."""
+    if mesh is None:
+        return {k: jnp.asarray(v) for k, v in batch.items()}
+    rows = batch["inputs"].shape[0]
+    out = {}
+    for k, v in batch.items():
+        spec = (batch_pspec(mesh, rows, v.ndim - 1)
+                if k in ("inputs", "labels") else P())
+        out[k] = jax.device_put(v, NamedSharding(mesh, spec))
+    return out
 
 
 def train(
@@ -118,16 +157,18 @@ def train(
     h_compute = obs.metrics.histogram("train.compute")
     g_workers = obs.metrics.gauge("train.n_workers")
 
+    params, opt_state = _init_state(model, optimizer, loop_cfg.seed, mesh)
     step_fn_cache: Dict[tuple, Callable] = {}
-    base_step = make_train_step(model, optimizer)
+    base_step = make_train_step(
+        model, optimizer,
+        param_shardings=(None if mesh is None
+                         else jax.tree.map(lambda p: p.sharding, params)),
+    )
 
     def compiled_step(shape):
         if shape not in step_fn_cache:
             step_fn_cache[shape] = jax.jit(base_step, donate_argnums=(0, 1))
         return step_fn_cache[shape]
-
-    params, opt_state = model.init(jax.random.PRNGKey(loop_cfg.seed)), None
-    opt_state = optimizer.init(params)
 
     ckpt = (
         CheckpointManager(loop_cfg.checkpoint_dir)
@@ -139,7 +180,11 @@ def train(
     sim_time = 0.0
     start_step = 0
     if ckpt is not None:
-        restored = ckpt.restore_latest({"params": params, "opt": opt_state})
+        restored = ckpt.restore_latest(
+            {"params": params, "opt": opt_state},
+            device_put_fn=(None if mesh is None
+                           else lambda v, like: jax.device_put(v, like.sharding)),
+        )
         if restored is not None:
             start_step, state, extras = restored
             params, opt_state = state["params"], state["opt"]
@@ -234,12 +279,15 @@ def train(
             # ---- batch sized for the CURRENT fleet ----------------------
             np_batch = batcher.batch_for_stage(stage.beta, n_workers=n_active)
             check_worker_major(np_batch["inputs"].shape[0], n_active)
-            batch = {
-                "inputs": jnp.asarray(np_batch["inputs"]),
-                "labels": jnp.asarray(np_batch["labels"]),
-                "worker_mask": jnp.asarray(mask),
-                "lr": jnp.float32(loop_cfg.lr),
-            }
+            batch = _place_batch(
+                {
+                    "inputs": np_batch["inputs"],
+                    "labels": np_batch["labels"],
+                    "worker_mask": mask,
+                    "lr": np.float32(loop_cfg.lr),
+                },
+                mesh,
+            )
             fn = compiled_step(np_batch["inputs"].shape)
             params, opt_state, metrics = fn(params, opt_state, batch)
 

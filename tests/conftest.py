@@ -1,10 +1,10 @@
 """Shared test setup.
 
 Must run before ANY jax import: jax locks the device count on first
-backend initialization, and the mesh/sharding tests (make_test_mesh,
-constrain_batch under a real mesh) need multiple devices on CPU-only CI.
-The subprocess-based tests (test_sharding_and_cost, test_pipeline_parallel)
-set their own XLA_FLAGS in the child process and are unaffected.
+backend initialization, and the mesh/sharding tests (make_mesh,
+constrain_batch under a real mesh, the sharded train step and loop) need
+multiple devices on CPU-only CI. The subprocess-based test_pipeline_parallel
+sets its own XLA_FLAGS in the child process and is unaffected.
 """
 
 import os

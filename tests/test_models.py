@@ -145,9 +145,18 @@ def test_causal_decoder_is_causal():
     )
 
 
-def test_pallas_attention_path_matches_default():
-    """cfg.use_pallas routes through the flash kernel (interpret mode on
-    CPU) and must agree with the chunked-jnp path."""
+def test_pallas_attention_path_matches_default(monkeypatch):
+    """cfg.use_pallas routes through the flash kernel and must agree with
+    the chunked-jnp path. The model never picks the kernel's interpreter
+    itself; here the test hands it the interpreted kernel."""
+    import functools
+
+    import repro.kernels.flash_attention as fa
+
+    monkeypatch.setattr(
+        fa, "flash_attention", functools.partial(fa.flash_attention,
+                                                 interpret=True)
+    )
     cfg = get_config("smollm-135m").reduced(n_layers=2, max_seq_len=128)
     cfg_p = dataclasses.replace(cfg, use_pallas=True)
     m0, m1 = build_model(cfg), build_model(cfg_p)
@@ -159,3 +168,17 @@ def test_pallas_attention_path_matches_default():
         np.asarray(h0, np.float32), np.asarray(h1, np.float32),
         atol=2e-3, rtol=2e-3,
     )
+
+
+def test_pallas_attention_path_refuses_non_tpu_backend():
+    """No quiet fallback: on a backend without the TPU compiler the
+    kernel path raises instead of running the Pallas interpreter."""
+    cfg = dataclasses.replace(
+        get_config("smollm-135m").reduced(n_layers=1, max_seq_len=128),
+        use_pallas=True,
+    )
+    model = build_model(cfg)
+    params = model.init(RNG)
+    toks = jnp.zeros((1, 128), jnp.int32)
+    with pytest.raises(ValueError, match="interpret"):
+        model.hidden(params, toks, jnp.arange(128))

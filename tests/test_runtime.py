@@ -3,6 +3,7 @@ data pipeline, collectives math, compression, telemetry, and the
 end-to-end adaptive train loop with failure injection."""
 
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -258,6 +259,40 @@ def test_checkpoint_pre_schema_checkpoints_still_load(tmp_path):
     np.testing.assert_array_equal(np.asarray(restored["w"]),
                                   np.asarray(state["w"]))
     assert extras["stage"]["k"] == 2
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float16", "int8", "float32"])
+def test_checkpoint_restores_each_leaf_dtype(tmp_path, dtype):
+    """npz has no name for bfloat16 (it reads back as raw 2-byte voids):
+    the meta records each leaf's dtype and restore brings it back."""
+    mgr = CheckpointManager(tmp_path)
+    state = {"w": (jnp.arange(12) - 6).reshape(3, 4).astype(dtype),
+             "b": jnp.ones((2,), jnp.float32)}
+    mgr.save(4, state)
+    restored, _ = mgr.restore(4, jax.eval_shape(lambda: state))
+    for k in state:
+        assert restored[k].dtype == state[k].dtype
+        np.testing.assert_array_equal(np.asarray(restored[k], np.float32),
+                                      np.asarray(state[k], np.float32))
+
+
+def test_compile_cache_dir_is_the_environments_or_the_checkouts(monkeypatch):
+    from repro.runtime.compile_cache import (
+        CHECKOUT_CACHE_DIR,
+        enable_compile_cache,
+    )
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before  # JAX's own
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE_DIR)
+        assert CHECKOUT_CACHE_DIR.parent == Path(__file__).resolve().parents[1]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 # ---------------------------------------------------------------------------

@@ -1,20 +1,12 @@
 """Sharding rules unit tests + loop-aware HLO cost analysis validation +
-a multi-device (forced host platform) end-to-end sharded train step run
-in a subprocess (so the device-count flag cannot leak into other tests).
+a multi-device end-to-end sharded train step on conftest's forced host
+devices.
 """
-
-import json
-import os
-import subprocess
-import sys
-import textwrap
 
 import numpy as np
 import pytest
 
 from repro.models.layers import ParamSpec
-
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 # ---------------------------------------------------------------------------
@@ -93,8 +85,6 @@ def test_hlo_cost_counts_scan_trip_counts():
     assert cost.unknown_trip_counts == 0
 
     xla = compiled.cost_analysis()
-    if isinstance(xla, (list, tuple)):  # older jax returns [dict]
-        xla = xla[0]
     # Sanity: XLA's own count misses the loop multiplier (that's WHY the
     # custom pass exists); if XLA ever fixes this, drop the custom pass.
     assert xla["flops"] < cost.flops
@@ -123,26 +113,24 @@ def test_hlo_cost_nested_loops():
 
 
 # ---------------------------------------------------------------------------
-# Multi-device sharded step (subprocess: needs forced device count)
+# Multi-device sharded step (conftest forces 8 host devices)
 # ---------------------------------------------------------------------------
 
-SUBPROCESS_SCRIPT = textwrap.dedent(
-    """
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import json
-    import jax, jax.numpy as jnp
+def test_sharded_train_step_8_devices():
+    import jax
+    import jax.numpy as jnp
     from repro.configs import get_config
-    from repro.dist.sharding import DEFAULT_RULES, activation_sharding
-    from repro.launch.specs import abstract_state, train_input_specs
-    from repro.configs.shapes import ShapeSpec
+    from repro.dist.sharding import (
+        DEFAULT_RULES,
+        activation_sharding,
+        make_sharding_fn,
+    )
+    from repro.launch.mesh import make_mesh
     from repro.models.model import Model
     from repro.optim.optimizers import get_optimizer
     from repro.runtime.steps import make_train_step
-    from repro.models.layers import init_from_specs
-    from repro.dist.sharding import make_sharding_fn
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"), devices=jax.devices()[:8])
     cfg = get_config("smollm-135m").reduced(vocab_size=512, max_seq_len=64)
     model = Model(cfg)
     opt = get_optimizer("adamw")
@@ -167,26 +155,8 @@ SUBPROCESS_SCRIPT = textwrap.dedent(
         for _ in range(5):
             params, opt_state, metrics = step(params, opt_state, batch)
             losses.append(float(metrics["loss"]))
-    print(json.dumps({
-        "losses": losses,
-        "n_devices": jax.device_count(),
-        "contributors": float(metrics["contributors"]),
-    }))
-    """
-)
-
-
-def test_sharded_train_step_8_devices():
-    env = dict(os.environ)
-    env["PYTHONPATH"] = SRC
-    out = subprocess.run(
-        [sys.executable, "-c", SUBPROCESS_SCRIPT],
-        capture_output=True, text=True, env=env, timeout=600,
-    )
-    assert out.returncode == 0, out.stderr[-2000:]
-    data = json.loads(out.stdout.strip().splitlines()[-1])
-    assert data["n_devices"] == 8
-    assert data["contributors"] == 3.0
-    losses = data["losses"]
+    assert mesh.size == 8
+    assert len(params["embed"].sharding.device_set) == 8
+    assert float(metrics["contributors"]) == 3.0
     assert all(np.isfinite(losses))
     assert losses[-1] < losses[0]

@@ -21,16 +21,16 @@ from repro.runtime.steps import make_train_step
 from repro.runtime.train_loop import FaultEvent, TrainLoopConfig, train
 
 
-def _tiny():
+def _tiny(**overrides):
     cfg = get_config("smollm-135m").reduced(
         n_layers=2, d_model=64, n_heads=2, n_kv_heads=2, head_dim=32,
-        d_ff=128, vocab_size=256, max_seq_len=64,
+        d_ff=128, vocab_size=256, max_seq_len=64, **overrides,
     )
     return cfg, build_model(cfg)
 
 
-def _setup(n=4, global_batch=16, seq_len=32):
-    cfg, model = _tiny()
+def _setup(n=4, global_batch=16, seq_len=32, **overrides):
+    cfg, model = _tiny(**overrides)
     strategy = StrategyConfig(
         "adaptive_kbeta", n=n, s=global_batch // n, k_max=n // 2,
         beta_grid=(0.5, 1.0),
@@ -128,6 +128,56 @@ def test_resume_replays_identical_history():
             assert a == b, f"resume diverged at step {a['step']}"
         assert out2["controller"].cfg.n == out1["controller"].cfg.n
         np.testing.assert_array_equal(out2["alive"], out1["alive"])
+
+
+def test_bf16_resume_replays_identical_history_and_params():
+    """Published configs keep their parameters in bfloat16, which npz
+    cannot name: the checkpoint must bring every leaf back bit-exact in
+    its own dtype, or the resumed run drifts from the uninterrupted one."""
+    _, model, strategy, delay, batcher = _setup(dtype="bfloat16")
+    with tempfile.TemporaryDirectory() as d:
+        mk = lambda: TrainLoopConfig(total_steps=30, log_every=0,
+                                     checkpoint_dir=d, checkpoint_every=20)
+        out1 = train(model, get_optimizer("adamw"), strategy, delay, batcher,
+                     mk())
+        _, model2, strategy2, delay2, batcher2 = _setup(dtype="bfloat16")
+        out2 = train(model2, get_optimizer("adamw"), strategy2, delay2,
+                     batcher2, mk())
+    assert out2["history"][0]["step"] == 20
+    assert out2["history"] == out1["history"][20:]
+    p1, p2 = jax.tree.leaves(out1["params"]), jax.tree.leaves(out2["params"])
+    assert {p.dtype for p in p2} == {jnp.dtype(jnp.bfloat16)}
+    for a, b in zip(p1, p2):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def test_sharded_loop_places_state_on_every_device():
+    """With a mesh, params and optimizer state are created sharded (FSDP
+    over ``data``) and each worker-major batch is split over ``data``:
+    no device holds the whole model, and the losses track the
+    single-device run of the same seed."""
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((4,), ("data",), devices=jax.devices()[:4])
+    runs = []
+    for m in (None, mesh):
+        _, model, strategy, delay, batcher = _setup()
+        runs.append(train(model, get_optimizer("adamw"), strategy, delay,
+                          batcher, TrainLoopConfig(total_steps=12, log_every=0),
+                          mesh=m))
+    single, sharded = runs
+    embed = sharded["params"]["embed"]
+    assert len({s.device for s in embed.addressable_shards}) == 4
+    assert all(s.data.size * 4 == embed.size for s in embed.addressable_shards)
+    mu = sharded["opt_state"].m["embed"]
+    assert all(s.data.size * 4 == mu.size for s in mu.addressable_shards)
+    l1 = np.array([h["loss"] for h in single["history"]])
+    l4 = np.array([h["loss"] for h in sharded["history"]])
+    np.testing.assert_allclose(l4, l1, rtol=1e-4)
+    assert [h["beta"] for h in single["history"]] == [
+        h["beta"] for h in sharded["history"]]
 
 
 def test_rejoin_restores_fleet_and_k_max():
