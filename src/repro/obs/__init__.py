@@ -12,6 +12,8 @@ Components (each usable standalone):
 
 * :class:`~repro.obs.trace.Tracer` — virtual-clock span/event tracer
   with Chrome/Perfetto ``trace_event`` export (``docs/observability.md``).
+* :func:`~repro.obs.spans.span` — wall-clock spans on the profiler's
+  clock; they record only while a ``jax.profiler`` trace is running.
 * :class:`~repro.obs.metrics.MetricsRegistry` — counters / gauges /
   streaming histograms, snapshot-able into ``BENCH_*.json``.
 * :class:`~repro.obs.decisions.DecisionLog` — every adaptive
@@ -28,6 +30,7 @@ from typing import Any, Dict
 from repro.obs.decisions import Decision, DecisionLog
 from repro.obs.log import LogRecord, StructuredLog
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
+from repro.obs.spans import span
 from repro.obs.trace import TID_MAIN, Tracer, validate_trace
 
 __all__ = [
@@ -36,6 +39,7 @@ __all__ = [
     "Tracer",
     "validate_trace",
     "TID_MAIN",
+    "span",
     "MetricsRegistry",
     "Counter",
     "Gauge",

@@ -11,11 +11,9 @@ tracer exports Chrome/Perfetto ``trace_event`` JSON (open
 Design rules (docs/observability.md):
 
 * **Virtual time is the timeline.** ``ts`` fields are virtual
-  microseconds. Wall-clock (``time.perf_counter``) is captured per event
-  in a parallel buffer and merged into ``args`` only on
-  ``to_json(include_wall=True)`` — the default export contains no wall
-  time, so two runs with identical seeds produce BYTE-IDENTICAL JSON
-  (pinned in tests/test_obs.py).
+  microseconds and the export holds no wall time, so two runs with
+  identical seeds produce BYTE-IDENTICAL JSON (pinned in
+  tests/test_obs.py). Wall time is the profiler's: ``repro.obs.span``.
 * **Zero cost when disabled.** A disabled tracer's methods return
   immediately (one attribute check); hot paths may additionally guard
   arg-dict construction on ``tracer.enabled``.
@@ -53,7 +51,6 @@ name            ph    emitted by
 from __future__ import annotations
 
 import json
-import time
 from typing import Any, Dict, List, Optional
 
 __all__ = ["Tracer", "validate_trace", "TID_MAIN"]
@@ -75,8 +72,6 @@ class Tracer:
     def __init__(self, enabled: bool = True):
         self.enabled = bool(enabled)
         self.events: List[Dict[str, Any]] = []
-        self._wall: List[float] = []        # perf_counter per event (parallel)
-        self._wall0 = time.perf_counter()
         self._procs: Dict[int, str] = {}    # pid -> display name
         self._next_pid = 1
         self._next_sid = 1
@@ -85,7 +80,6 @@ class Tracer:
     # -- low-level emit ------------------------------------------------------
     def _emit(self, ev: Dict[str, Any]) -> None:
         self.events.append(ev)
-        self._wall.append(time.perf_counter() - self._wall0)
 
     # -- processes (one per virtual clock) -----------------------------------
     def register_process(self, name: str) -> int:
@@ -190,28 +184,17 @@ class Tracer:
         })
 
     # -- export ---------------------------------------------------------------
-    def to_json(self, include_wall: bool = False) -> str:
-        """Chrome ``trace_event`` JSON. Without ``include_wall`` the
-        output is a pure function of the virtual execution — identical
-        seeds produce byte-identical strings."""
-        if include_wall:
-            events = []
-            for ev, w in zip(self.events, self._wall):
-                ev = dict(ev)
-                args = dict(ev.get("args", ()))
-                args["wall_s"] = round(w, 6)
-                ev["args"] = args
-                events.append(ev)
-        else:
-            events = self.events
+    def to_json(self) -> str:
+        """Chrome ``trace_event`` JSON: a pure function of the virtual
+        execution — identical seeds produce byte-identical strings."""
         return json.dumps(
-            {"traceEvents": events, "displayTimeUnit": "ms"},
+            {"traceEvents": self.events, "displayTimeUnit": "ms"},
             sort_keys=True, separators=(",", ":"),
         )
 
-    def export(self, path: str, include_wall: bool = False) -> None:
+    def export(self, path: str) -> None:
         with open(path, "w") as f:
-            f.write(self.to_json(include_wall))
+            f.write(self.to_json())
 
 
 def validate_trace(events: List[Dict[str, Any]]) -> List[str]:

@@ -49,7 +49,7 @@ from repro.dist.sharding import (
     batch_pspec,
 )
 from repro.models.model import Model
-from repro.obs import NULL_OBS, Observability
+from repro.obs import NULL_OBS, Observability, span
 from repro.optim.optimizers import Optimizer
 from repro.runtime.checkpoint import CheckpointManager
 # FaultEvent moved to repro.runtime.faults (PR 7) so the serving plane can
@@ -134,7 +134,11 @@ def train(
     ``sim_time`` lane, chaos/demotion transitions as ``fault`` instants,
     the per-step wait/compute split as histograms, and every stage
     switch as a ``train.stage`` decision-log entry carrying the censored
-    telemetry it was priced from."""
+    telemetry it was priced from.
+
+    Whatever ``obs`` is, each step's phases are wall spans
+    (``repro.train.*``, docs/observability.md) that record while a
+    ``jax.profiler`` trace is running."""
     obs = obs or NULL_OBS
     tr_obs = obs.tracer
     pid = tr_obs.register_process("train")
@@ -208,178 +212,194 @@ def train(
     ctx = activation_sharding(mesh) if mesh is not None else contextlib.nullcontext()
     with ctx:
         for step in range(start_step, loop_cfg.total_steps):
-            # ---- chaos events -------------------------------------------
-            for ev in schedule.get(step, ()):
-                applied = False
-                if ev.kind == "fail" and alive[ev.worker]:
-                    alive[ev.worker] = False
-                    ctrl.remove_worker()
-                    applied = True
-                elif ev.kind == "rejoin" and not alive[ev.worker]:
-                    alive[ev.worker] = True
-                    slow_factor[ev.worker] = ev.factor
-                    tracker.reset_worker(ev.worker)
-                    ctrl.add_worker()
-                    applied = True
-                elif ev.kind == "slow":
-                    slow_factor[ev.worker] = ev.factor
-                    applied = True
-                if applied and obs.enabled:
-                    obs.metrics.counter(f"train.fault.{ev.kind}").inc()
-                    tr_obs.instant(
-                        "fault", pid, sim_time,
-                        args={"kind": ev.kind, "worker": ev.worker,
-                              "step": step},
-                    )
-
-            # ---- pending demotions from telemetry -----------------------
-            if loop_cfg.demote_after_ewma is not None:
-                for w in tracker.persistent_stragglers(loop_cfg.demote_after_ewma):
-                    if alive[w] and alive.sum() > 1:
-                        alive[w] = False
-                        ctrl.remove_worker()
-                        if obs.enabled:
-                            obs.metrics.counter("train.demotions").inc()
+            with span("repro.train.step", step=step):
+                with span("repro.train.plan"):
+                    # ---- chaos events ---------------------------------------
+                    for ev in schedule.get(step, ()):
+                        applied = False
+                        if ev.kind == "fail" and alive[ev.worker]:
+                            alive[ev.worker] = False
+                            ctrl.remove_worker()
+                            applied = True
+                        elif ev.kind == "rejoin" and not alive[ev.worker]:
+                            alive[ev.worker] = True
+                            slow_factor[ev.worker] = ev.factor
+                            tracker.reset_worker(ev.worker)
+                            ctrl.add_worker()
+                            applied = True
+                        elif ev.kind == "slow":
+                            slow_factor[ev.worker] = ev.factor
+                            applied = True
+                        if applied and obs.enabled:
+                            obs.metrics.counter(f"train.fault.{ev.kind}").inc()
                             tr_obs.instant(
-                                "demote", pid, sim_time,
-                                args={"worker": int(w), "step": step},
+                                "fault", pid, sim_time,
+                                args={"kind": ev.kind, "worker": ev.worker,
+                                      "step": step},
                             )
 
-            # ---- the n-contract: controller and fleet must agree --------
-            n_active = int(alive.sum())
-            if n_active != ctrl.cfg.n:
-                raise RuntimeError(
-                    f"fleet/controller divergence: {n_active} alive workers "
-                    f"but controller prices n={ctrl.cfg.n}"
-                )
-            active_ids = np.nonzero(alive)[0]
-            stage = ctrl.stage
+                    # ---- pending demotions from telemetry -------------------
+                    if loop_cfg.demote_after_ewma is not None:
+                        for w in tracker.persistent_stragglers(loop_cfg.demote_after_ewma):
+                            if alive[w] and alive.sum() > 1:
+                                alive[w] = False
+                                ctrl.remove_worker()
+                                if obs.enabled:
+                                    obs.metrics.counter("train.demotions").inc()
+                                    tr_obs.instant(
+                                        "demote", pid, sim_time,
+                                        args={"worker": int(w), "step": step},
+                                    )
 
-            # ---- response times + fastest-k mask ------------------------
-            # Sample the FULL original fleet every step so the RNG stream
-            # consumption is independent of membership (exact resume and
-            # run-to-run comparability), then restrict to active workers.
-            z_full = delay_model.sample(rng, n0, stage.beta) * slow_factor
-            z_act = z_full[active_ids]
-            k_eff = min(stage.k, n_active)
-            order = np.argpartition(z_act, k_eff - 1)[:k_eff]
-            t_step = float(z_act[order].max())
-            t0_step = sim_time
-            sim_time += t_step
-            mask = np.zeros(n_active, np.float32)
-            mask[order] = 1.0
+                    # ---- the n-contract: controller and fleet must agree ----
+                    n_active = int(alive.sum())
+                    if n_active != ctrl.cfg.n:
+                        raise RuntimeError(
+                            f"fleet/controller divergence: {n_active} alive workers "
+                            f"but controller prices n={ctrl.cfg.n}"
+                        )
+                    active_ids = np.nonzero(alive)[0]
+                    stage = ctrl.stage
 
-            # ---- censored telemetry -------------------------------------
-            # Only the k waited-for times are observable on real hardware;
-            # everyone else is censored at the step time z_(k).
-            selected = np.zeros(n0, bool)
-            selected[active_ids[order]] = True
-            tracker.observe(z_full, alive, observed=selected, censor_level=t_step)
+                    # ---- response times + fastest-k mask --------------------
+                    # Sample the FULL original fleet every step so the RNG
+                    # stream consumption is independent of membership (exact
+                    # resume and run-to-run comparability), then restrict to
+                    # active workers.
+                    z_full = delay_model.sample(rng, n0, stage.beta) * slow_factor
+                    z_act = z_full[active_ids]
+                    k_eff = min(stage.k, n_active)
+                    order = np.argpartition(z_act, k_eff - 1)[:k_eff]
+                    t_step = float(z_act[order].max())
+                    t0_step = sim_time
+                    sim_time += t_step
+                    mask = np.zeros(n_active, np.float32)
+                    mask[order] = 1.0
 
-            # ---- batch sized for the CURRENT fleet ----------------------
-            np_batch = batcher.batch_for_stage(stage.beta, n_workers=n_active)
-            check_worker_major(np_batch["inputs"].shape[0], n_active)
-            batch = _place_batch(
-                {
-                    "inputs": np_batch["inputs"],
-                    "labels": np_batch["labels"],
-                    "worker_mask": mask,
-                    "lr": np.float32(loop_cfg.lr),
-                },
-                mesh,
-            )
-            fn = compiled_step(np_batch["inputs"].shape)
-            params, opt_state, metrics = fn(params, opt_state, batch)
+                    # ---- censored telemetry ---------------------------------
+                    # Only the k waited-for times are observable on real
+                    # hardware; everyone else is censored at the step time z_(k).
+                    selected = np.zeros(n0, bool)
+                    selected[active_ids[order]] = True
+                    tracker.observe(z_full, alive, observed=selected, censor_level=t_step)
 
-            loss = float(metrics["loss"])
-            ctrl.observe(
-                loss=loss,
-                response_times=np.sort(z_act[order]),
-                n_unobserved=n_active - k_eff,
-            )
-            switched = ctrl.maybe_advance()
-
-            if obs.enabled:
-                observed = np.sort(z_act[order])
-                h_step.observe(t_step)
-                h_wait.observe(t_step - float(observed[0]))
-                h_compute.observe(float(observed.mean()))
-                g_workers.set(n_active)
-                tr_obs.complete(
-                    "train_step", pid, t0_step, sim_time,
-                    args={"step": step, "k": stage.k,
-                          "beta": float(stage.beta),
-                          "n_workers": n_active,
-                          "loss": round(loss, 6)},
-                )
-                if switched is not None:
-                    tr_obs.instant(
-                        "stage_switch", pid, sim_time,
-                        args={"step": step, "k": switched.k,
-                              "beta": float(switched.beta)},
+                # ---- batch sized for the CURRENT fleet ----------------------
+                # Called from this frame, not a helper: a batcher may read the
+                # loop's state from its caller's frame (tests/test_wall_spans.py).
+                with span("repro.train.batch"):
+                    np_batch = batcher.batch_for_stage(stage.beta, n_workers=n_active)
+                    check_worker_major(np_batch["inputs"].shape[0], n_active)
+                with span("repro.train.put"):
+                    batch = _place_batch(
+                        {
+                            "inputs": np_batch["inputs"],
+                            "labels": np_batch["labels"],
+                            "worker_mask": mask,
+                            "lr": np.float32(loop_cfg.lr),
+                        },
+                        mesh,
                     )
-                    fitted = ctrl.current_model()
-                    obs.decisions.record(
-                        "train.stage",
-                        {"k": switched.k, "beta": float(switched.beta)},
-                        {"stage_idx": ctrl.stage_idx,
-                         "n": ctrl.cfg.n,
-                         "rt_samples": len(ctrl._rt_samples),
-                         "rt_censored": int(sum(ctrl._rt_censored)),
-                         "lambda_y": (
-                             round(float(fitted.lambda_y), 6)
-                             if fitted is not None else None
-                         )},
-                        step=step, vtime=sim_time,
+                with span("repro.train.dispatch"):
+                    fn = compiled_step(np_batch["inputs"].shape)
+                    params, opt_state, metrics = fn(params, opt_state, batch)
+
+                # The one place the loop blocks on the step program.
+                with span("repro.train.wait"):
+                    loss = float(metrics["loss"])
+
+                with span("repro.train.control"):
+                    ctrl.observe(
+                        loss=loss,
+                        response_times=np.sort(z_act[order]),
+                        n_unobserved=n_active - k_eff,
                     )
+                    switched = ctrl.maybe_advance()
 
-            history.append(
-                {
-                    "step": step,
-                    "loss": loss,
-                    "k": stage.k,
-                    "beta": stage.beta,
-                    "n_workers": n_active,
-                    "sim_time": sim_time,
-                    "contributors": float(metrics["contributors"]),
-                    "grad_norm": float(metrics["grad_norm"]),
-                }
-            )
-            if switched is not None:
-                history[-1]["switched_to"] = (switched.k, switched.beta)
+                    if obs.enabled:
+                        observed = np.sort(z_act[order])
+                        h_step.observe(t_step)
+                        h_wait.observe(t_step - float(observed[0]))
+                        h_compute.observe(float(observed.mean()))
+                        g_workers.set(n_active)
+                        tr_obs.complete(
+                            "train_step", pid, t0_step, sim_time,
+                            args={"step": step, "k": stage.k,
+                                  "beta": float(stage.beta),
+                                  "n_workers": n_active,
+                                  "loss": round(loss, 6)},
+                        )
+                        if switched is not None:
+                            tr_obs.instant(
+                                "stage_switch", pid, sim_time,
+                                args={"step": step, "k": switched.k,
+                                      "beta": float(switched.beta)},
+                            )
+                            fitted = ctrl.current_model()
+                            obs.decisions.record(
+                                "train.stage",
+                                {"k": switched.k, "beta": float(switched.beta)},
+                                {"stage_idx": ctrl.stage_idx,
+                                 "n": ctrl.cfg.n,
+                                 "rt_samples": len(ctrl._rt_samples),
+                                 "rt_censored": int(sum(ctrl._rt_censored)),
+                                 "lambda_y": (
+                                     round(float(fitted.lambda_y), 6)
+                                     if fitted is not None else None
+                                 )},
+                                step=step, vtime=sim_time,
+                            )
 
-            if ckpt is not None and (step + 1) % loop_cfg.checkpoint_every == 0:
-                ckpt.save_async(
-                    step + 1,
-                    {"params": params, "opt": opt_state},
-                    extras={
-                        "stage": dataclasses.asdict(ctrl.stage),  # legacy key
-                        "controller": ctrl.state_dict(),
-                        "tracker": tracker.state_dict(),
-                        "alive": [int(a) for a in alive],
-                        "slow_factor": [float(f) for f in slow_factor],
-                        "sim_time": sim_time,
-                        "rng_state": rng.bit_generator.state,
-                        "stream_rng_state": batcher.stream.rng.bit_generator.state,
-                    },
-                )
-
-            if loop_cfg.log_every and step % loop_cfg.log_every == 0:
-                # The structured record is the source of truth; the
-                # legacy print stays as its stdout view unless the log
-                # is already echoing its own rendering.
-                obs.log.emit(
-                    "train_step", t=sim_time, step=step,
-                    loss=round(loss, 4), k=stage.k,
-                    beta=float(stage.beta), workers=n_active,
-                )
-                if not obs.log.echo:
-                    print(
-                        f"step {step:5d} loss {loss:8.4f} k={stage.k:2d} "
-                        f"beta={stage.beta:4.2f} t={sim_time:9.2f} "
-                        f"workers={n_active}",
-                        flush=True,
+                    with span("repro.train.read"):
+                        contributors = float(metrics["contributors"])
+                    with span("repro.train.read"):
+                        grad_norm = float(metrics["grad_norm"])
+                    history.append(
+                        {
+                            "step": step,
+                            "loss": loss,
+                            "k": stage.k,
+                            "beta": stage.beta,
+                            "n_workers": n_active,
+                            "sim_time": sim_time,
+                            "contributors": contributors,
+                            "grad_norm": grad_norm,
+                        }
                     )
+                    if switched is not None:
+                        history[-1]["switched_to"] = (switched.k, switched.beta)
+
+                    if ckpt is not None and (step + 1) % loop_cfg.checkpoint_every == 0:
+                        ckpt.save_async(
+                            step + 1,
+                            {"params": params, "opt": opt_state},
+                            extras={
+                                "stage": dataclasses.asdict(ctrl.stage),  # legacy key
+                                "controller": ctrl.state_dict(),
+                                "tracker": tracker.state_dict(),
+                                "alive": [int(a) for a in alive],
+                                "slow_factor": [float(f) for f in slow_factor],
+                                "sim_time": sim_time,
+                                "rng_state": rng.bit_generator.state,
+                                "stream_rng_state": batcher.stream.rng.bit_generator.state,
+                            },
+                        )
+
+                    if loop_cfg.log_every and step % loop_cfg.log_every == 0:
+                        # The structured record is the source of truth; the
+                        # legacy print stays as its stdout view unless the log
+                        # is already echoing its own rendering.
+                        obs.log.emit(
+                            "train_step", t=sim_time, step=step,
+                            loss=round(loss, 4), k=stage.k,
+                            beta=float(stage.beta), workers=n_active,
+                        )
+                        if not obs.log.echo:
+                            print(
+                                f"step {step:5d} loss {loss:8.4f} k={stage.k:2d} "
+                                f"beta={stage.beta:4.2f} t={sim_time:9.2f} "
+                                f"workers={n_active}",
+                                flush=True,
+                            )
 
     if ckpt is not None:
         ckpt.wait()

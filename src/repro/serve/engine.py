@@ -45,7 +45,6 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -54,7 +53,7 @@ import numpy as np
 
 from repro.models.attention import NULL_BLOCK
 from repro.models.layers import ParamSpec, is_paged_spec, slot_mask_select
-from repro.obs import NULL_OBS, Observability
+from repro.obs import NULL_OBS, Observability, span
 from repro.runtime.steps import (
     make_slot_decode_step,
     make_slot_prefill_step,
@@ -144,15 +143,10 @@ class EngineStats:
     migrated_out: int = 0         # requests exported as MigrationTickets
     migrated_in: int = 0          # tickets restored into this engine
     virtual_seconds: float = 0.0
-    wall_seconds: float = 0.0
 
     @property
     def tokens_per_vsec(self) -> float:
         return self.generated_tokens / max(self.virtual_seconds, 1e-12)
-
-    @property
-    def tokens_per_wsec(self) -> float:
-        return self.generated_tokens / max(self.wall_seconds, 1e-12)
 
 
 @model_scoped_cache
@@ -323,43 +317,45 @@ class ServeEngine:
     ) -> int:
         """``deadline``: absolute virtual-time deadline; None defers to
         the scheduler's ``deadline_ticks`` default (stamped at
-        admission)."""
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        if prompt.size + max_new_tokens > self.pool.max_len:
-            raise ValueError(
-                f"prompt({prompt.size}) + max_new_tokens({max_new_tokens}) "
-                f"exceeds max_len({self.pool.max_len})"
-            )
-        if self.pool.paged:
-            mgr = self.pool.manager
-            need = mgr.blocks_for(prompt.size + max_new_tokens)
-            if need > mgr.num_blocks:
-                # Reject outright: a request bigger than the whole arena
-                # could never be admitted, even with the pool idle.
+        admission). The wall span ``repro.engine.submit`` marks the
+        request's wall arrival."""
+        with span("repro.engine.submit", rid=self._next_rid):
+            prompt = np.asarray(prompt, np.int32).reshape(-1)
+            if prompt.size + max_new_tokens > self.pool.max_len:
                 raise ValueError(
-                    f"request needs {need} blocks but the arena has only "
-                    f"{mgr.num_blocks} — raise arena_blocks or block_size"
+                    f"prompt({prompt.size}) + max_new_tokens({max_new_tokens}) "
+                    f"exceeds max_len({self.pool.max_len})"
                 )
-        rid = self._next_rid
-        self._next_rid += 1
-        req = Request(
-            rid, prompt, int(max_new_tokens), float(arrival),
-            deadline=deadline,
-        )
-        self._requests[rid] = req
-        self.sched.submit(req)
-        if self._tr.enabled:
-            # The span opens at this engine's LOCAL clock, not at the
-            # logical arrival: a hedge copy can be handed to a replica
-            # whose clock is behind the arrival stamp, and span ends
-            # must never precede their begins.
-            self._span_ids[rid] = self._tr.begin_span(
-                "request", self.pid, self.sched.clock.now,
-                args={"rid": rid, "arrival": float(arrival),
-                      "prompt_len": int(prompt.size),
-                      "max_new_tokens": int(max_new_tokens)},
+            if self.pool.paged:
+                mgr = self.pool.manager
+                need = mgr.blocks_for(prompt.size + max_new_tokens)
+                if need > mgr.num_blocks:
+                    # Reject outright: a request bigger than the whole arena
+                    # could never be admitted, even with the pool idle.
+                    raise ValueError(
+                        f"request needs {need} blocks but the arena has only "
+                        f"{mgr.num_blocks} — raise arena_blocks or block_size"
+                    )
+            rid = self._next_rid
+            self._next_rid += 1
+            req = Request(
+                rid, prompt, int(max_new_tokens), float(arrival),
+                deadline=deadline,
             )
-        return rid
+            self._requests[rid] = req
+            self.sched.submit(req)
+            if self._tr.enabled:
+                # The span opens at this engine's LOCAL clock, not at the
+                # logical arrival: a hedge copy can be handed to a replica
+                # whose clock is behind the arrival stamp, and span ends
+                # must never precede their begins.
+                self._span_ids[rid] = self._tr.begin_span(
+                    "request", self.pid, self.sched.clock.now,
+                    args={"rid": rid, "arrival": float(arrival),
+                          "prompt_len": int(prompt.size),
+                          "max_new_tokens": int(max_new_tokens)},
+                )
+            return rid
 
     def _end_request_span(self, req: Request, outcome: str, ts: float) -> None:
         """Close a request's lifecycle span exactly once, whatever path
@@ -656,14 +652,15 @@ class ServeEngine:
         # prefill a stale arena missing the forked block's rows.
         slot_caches = (self._fresh_slot_caches() if first
                        else pool.read_slot(slot))
-        logits, slot_caches = self._prefill(
-            self.params,
-            jnp.asarray(chunk),
-            slot_caches,
-            jnp.asarray([n_tok], jnp.int32),
-            jnp.int32(start),
-            pool.tables_device(slot),
-        )
+        with span("repro.engine.dispatch"):
+            logits, slot_caches = self._prefill(
+                self.params,
+                jnp.asarray(chunk),
+                slot_caches,
+                jnp.asarray([n_tok], jnp.int32),
+                jnp.int32(start),
+                pool.tables_device(slot),
+            )
         pool.write_slot(slot, slot_caches, position=start + n_tok)
         if self.speculative:
             # The draft cache must hold the same prefix (same bucketed
@@ -687,7 +684,8 @@ class ServeEngine:
                 self._pending[slot] = np.int32(req.tokens[-1])
                 self._decoding[slot] = True
             else:
-                tok = int(jnp.argmax(logits[0, -1]))
+                with span("repro.engine.sync"):
+                    tok = int(jnp.argmax(logits[0, -1]))
                 self._emit(req, tok)
                 if self._finished(req):     # max_new_tokens == 1
                     self._free_slot(slot)
@@ -830,15 +828,17 @@ class ServeEngine:
                     lambda s=slot, p=pos: pool.ensure_writable(s, p, p + 1),
                 )
         mask = self._decoding.copy()
-        tokens = jnp.asarray(self._pending[:, None])
-        positions = jnp.asarray(np.clip(pool.positions, 0, pool.max_len - 1))
-        logits, pool.caches = self._decode(
-            self.params, tokens, pool.caches, positions, jnp.asarray(mask),
-            pool.tables_device(),
-        )
+        with span("repro.engine.dispatch"):
+            tokens = jnp.asarray(self._pending[:, None])
+            positions = jnp.asarray(np.clip(pool.positions, 0, pool.max_len - 1))
+            logits, pool.caches = self._decode(
+                self.params, tokens, pool.caches, positions, jnp.asarray(mask),
+                pool.tables_device(),
+            )
         self.sched.on_decode_tick()
         self.stats.decode_ticks += 1
-        next_tok = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1), np.int32)
+        with span("repro.engine.sync"):
+            next_tok = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1), np.int32)
         for slot in np.nonzero(mask)[0]:
             slot = int(slot)
             pool.positions[slot] += 1
@@ -922,12 +922,14 @@ class ServeEngine:
         starts = pool.positions.copy()
         for slot in slots:
             pool.ensure_rows(int(slot), int(starts[slot]) + int(n_input[slot]))
-        positions = jnp.asarray(np.clip(starts, 0, pool.max_len - 1))
-        greedy, pool.caches = self._verify(
-            self.params, jnp.asarray(inputs), pool.caches,
-            jnp.asarray(n_input), positions, pool.tables_device(),
-        )
-        greedy = np.asarray(greedy, np.int32)
+        with span("repro.engine.dispatch"):
+            positions = jnp.asarray(np.clip(starts, 0, pool.max_len - 1))
+            greedy, pool.caches = self._verify(
+                self.params, jnp.asarray(inputs), pool.caches,
+                jnp.asarray(n_input), positions, pool.tables_device(),
+            )
+        with span("repro.engine.sync"):
+            greedy = np.asarray(greedy, np.int32)
 
         # -- acceptance: exact argmax chain, then emit + rewind --------------
         n_commit = np.zeros(n_slots, np.int32)
@@ -1017,44 +1019,51 @@ class ServeEngine:
     def step(self) -> str:
         """Run one scheduler action; returns its kind. Deadlines are
         policed here, before the action is chosen — an expired request's
-        slot (and blocks) are free by the time admission is priced."""
-        self._expire_deadlines()
-        if self.prefix_sharing:
-            self._maybe_preempt_for_admission()
-        kind, req = self.sched.next_action(
-            self.pool.n_active, self.pool.n_free, self._can_admit
-        )
-        if kind == "prefill":
-            self._do_prefill(req)
-        elif kind == "decode":
-            if self.speculative:
-                self._do_spec_round()
-            else:
-                self._do_decode()
-        elif kind == "idle":
-            t0 = self.sched.clock.now
-            self.sched.on_idle()
-            self.events.append(("idle", self.sched.clock.now, -1))
-            if self._tr.enabled:
-                self._tr.complete("idle", self.pid, t0, self.sched.clock.now)
-        if self.obs.enabled and kind != "done":
-            self._g_slots.set(self.pool.n_active)
-            values = {"slots": int(self.pool.n_active)}
-            if self.pool.paged:
-                used = self.pool.manager.n_used_blocks
-                self._g_blocks.set(used)
-                values["blocks"] = int(used)
-            self._tr.counter(
-                "occupancy", self.pid, self.sched.clock.now, values
-            )
-        return kind
+        slot (and blocks) are free by the time admission is priced.
+
+        Each step and its phases are wall spans (``repro.engine.*``,
+        docs/observability.md) that record while a ``jax.profiler`` trace
+        is running."""
+        with span("repro.engine.step"):
+            with span("repro.engine.schedule"):
+                self._expire_deadlines()
+                if self.prefix_sharing:
+                    self._maybe_preempt_for_admission()
+                kind, req = self.sched.next_action(
+                    self.pool.n_active, self.pool.n_free, self._can_admit
+                )
+            if kind == "prefill":
+                with span("repro.engine.prefill", rid=req.rid):
+                    self._do_prefill(req)
+            elif kind == "decode":
+                if self.speculative:
+                    with span("repro.engine.spec"):
+                        self._do_spec_round()
+                else:
+                    with span("repro.engine.decode"):
+                        self._do_decode()
+            elif kind == "idle":
+                t0 = self.sched.clock.now
+                self.sched.on_idle()
+                self.events.append(("idle", self.sched.clock.now, -1))
+                if self._tr.enabled:
+                    self._tr.complete("idle", self.pid, t0, self.sched.clock.now)
+            if self.obs.enabled and kind != "done":
+                self._g_slots.set(self.pool.n_active)
+                values = {"slots": int(self.pool.n_active)}
+                if self.pool.paged:
+                    used = self.pool.manager.n_used_blocks
+                    self._g_blocks.set(used)
+                    values["blocks"] = int(used)
+                self._tr.counter(
+                    "occupancy", self.pid, self.sched.clock.now, values
+                )
+            return kind
 
     def run(self) -> Dict[int, Request]:
         """Drive until every submitted request completes."""
-        t0 = time.perf_counter()
         while self.step() != "done":
             pass
-        self.stats.wall_seconds += time.perf_counter() - t0
         self.stats.virtual_seconds = self.sched.clock.now
         return dict(self._requests)
 
