@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["decode_attention_fwd", "paged_decode_attention_fwd"]
+__all__ = ["decode_attention_fwd", "paged_decode_attention_fwd", "whole_tiles"]
 
 NEG_INF = -1e30
 
@@ -176,14 +176,142 @@ def decode_attention_fwd(
 
 # ---------------------------------------------------------------------------
 # Paged flash-decode: the KV cache is a global block arena + per-sequence
-# block tables (vLLM-style). The grid's sequential dim walks TABLE SLOTS,
-# not cache rows: the block table is scalar-prefetched (SMEM before the
-# body runs) so each K/V BlockSpec index_map gathers the right arena row,
-# and slots past ceil(length/block) clamp to the last live block — Pallas
-# skips the HBM->VMEM copy when the mapped block index repeats, and
-# @pl.when skips the compute. Decode traffic and FLOPs are therefore
-# proportional to LIVE tokens, not to n_slots * max_len.
+# block tables (vLLM-style), and decode reads only each sequence's LIVE
+# blocks: traffic and FLOPs scale with live tokens, not with
+# n_slots * max_len.
+#
+# The arena is read in its own layout (num_blocks + 1, block_size, Hkv,
+# D). Viewing it as (..., Hkv * D) is not free on the TPU: a 2-wide
+# second-minor dim is tiled (2, 128), and that reshape copies the whole
+# arena on every call.
+#
+# Where a block is whole tiles of that layout (D a multiple of 128 lanes,
+# Hkv a power of two of at least one 32-bit word's rows), one kernel
+# invocation walks every sequence's live blocks in groups of ``pages``
+# table slots: the arena stays in HBM and each block is DMA'd into a
+# double-buffered VMEM group through the scalar-prefetched block table,
+# the next group's copies (the next sequence's first group included) in
+# flight while this one computes. Groups past ceil(length / block_size)
+# blocks cost neither a copy nor compute; the last group's slots past
+# the live blocks repeat the last live block. A group's (rows, Hkv, D)
+# K and V are viewed as (rows * Hkv, D), row r's kv head h at
+# r * Hkv + h: every query head scores every row and keeps those of its
+# own kv head (the block-diagonal query's arithmetic), with one matmul
+# for the scores and one for the values.
+#
+# Other geometries (smollm-135m's 3 kv heads of 64) cannot be sliced out
+# of the tiled arena by a DMA. For them a (B, T) grid walks one block a
+# step over the arena viewed as (..., Hkv * D), with a block-diagonal
+# query: the scalar-prefetched table drives each K/V BlockSpec's
+# index_map, dead slots clamp to the last live block (a repeated block
+# index is not copied again) and @pl.when skips their compute. The model
+# does not call it (``attention.paged_decode_attention`` gathers there).
 # ---------------------------------------------------------------------------
+
+#: Table slots per group: 8 blocks of 16 rows = 128 rows a DMA group. On
+#: a v5e at the chat cell's geometry 8 was faster than 4 and 16 with 8
+#: live lanes of 300-3000 rows, and within 3 % of 16 with 32 live lanes
+#: of 1000-2048 (PERF.md).
+PAGES_PER_GROUP = 8
+
+
+def whole_tiles(Hkv: int, D: int, dtype) -> bool:
+    """A (block_size, Hkv, D) block is whole tiles of the arena's TPU
+    layout, so a DMA can slice it out."""
+    packing = 4 // jnp.dtype(dtype).itemsize
+    return D % 128 == 0 and Hkv >= packing and Hkv & (Hkv - 1) == 0
+
+
+def _paged_flat_kernel(tab_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                       k_buf, v_buf, sems, *, scale, block_size, pages):
+    B = len_ref.shape[0]
+    H = q_ref.shape[1]
+    Hkv = k_buf.shape[3]
+    rows = pages * block_size
+    owner = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0) // (H // Hkv)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, rows * Hkv), 1)
+
+    def n_blocks(b):
+        return jax.lax.div(len_ref[b] + block_size - 1, block_size)
+
+    def start(b, g, slot):
+        # Every slot of the group is copied, those past the live blocks
+        # repeating the last live one (whose rows the mask drops), so one
+        # wait a buffer covers the whole group.
+        n = n_blocks(b)
+        for j in range(pages):
+            bid = tab_ref[b, jnp.minimum(g * pages + j, n - 1)]
+            for i, (src, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                pltpu.make_async_copy(src.at[bid], buf.at[slot, j],
+                                      sems.at[i, slot]).start()
+
+    def wait(slot):
+        for i, buf in enumerate((k_buf, v_buf)):
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                  sems.at[i, slot]).wait()
+
+    def next_live(b):
+        """The first sequence at or after ``b`` with a live row (B if none)."""
+        return jax.lax.while_loop(
+            lambda x: (x < B) & (len_ref[jnp.minimum(x, B - 1)] == 0),
+            lambda x: x + 1, b)
+
+    o_ref[...] = jnp.zeros_like(o_ref)     # sequences with no live row
+    first = next_live(0)
+
+    @pl.when(first < B)
+    def _():
+        start(first, 0, 0)
+
+    def sequence(b, w):
+        length = len_ref[b]
+        n_groups = jax.lax.div(n_blocks(b) + pages - 1, pages)
+        # The jnp path's rounding: q * scale in q's dtype, both MXU
+        # operands in the arena's dtype, accumulated in float32.
+        q = (q_ref[b] * jnp.asarray(scale, q_ref.dtype)).astype(k_buf.dtype)
+
+        def group(g, carry):
+            w, m_prev, l_prev, acc_prev = carry
+            slot = w % 2
+            more = g + 1 < n_groups
+            nb = jnp.where(more, b, next_live(b + 1))
+
+            @pl.when(nb < B)
+            def _():
+                start(nb, jnp.where(more, g + 1, 0), 1 - slot)
+
+            wait(slot)
+            k = k_buf[slot].reshape(rows * Hkv, -1)
+            v = v_buf[slot].reshape(rows * Hkv, -1)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                  # (H, rows*Hkv)
+            own = (col % Hkv == owner) & (g * rows + col // Hkv < length)
+            s = jnp.where(own, s, NEG_INF)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                  # (H, Dv)
+            l_new = l_prev * corr + p.sum(axis=1, keepdims=True)
+            return w + 1, m_new, l_new, acc_prev * corr + pv
+
+        init = (w, jnp.full((H, 1), NEG_INF, jnp.float32),
+                jnp.zeros((H, 1), jnp.float32),
+                jnp.zeros((H, v_buf.shape[-1]), jnp.float32))
+        w, _, l, acc = jax.lax.fori_loop(0, n_groups, group, init)
+
+        @pl.when(n_groups > 0)
+        def _():
+            o_ref[b] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+        return w
+
+    jax.lax.fori_loop(0, B, sequence, 0)
+
 
 def _paged_kernel(tab_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, scale, block_size):
@@ -221,6 +349,30 @@ def paged_decode_attention_fwd(
                                    k_arena.shape[2], v_arena.shape[3])
     T = block_tables.shape[1]
     scale = 1.0 / math.sqrt(D)
+
+    if whole_tiles(Hkv, D, k_arena.dtype) and whole_tiles(Hkv, Dv, v_arena.dtype):
+        pages = min(PAGES_PER_GROUP, T)
+        hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+        vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # block_tables, lengths
+            grid=(1,),
+            in_specs=[vmem, hbm, hbm],
+            out_specs=vmem,
+            scratch_shapes=[
+                pltpu.VMEM((2, pages, block_size, Hkv, D), k_arena.dtype),
+                pltpu.VMEM((2, pages, block_size, Hkv, Dv), v_arena.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),   # (K / V, buffer)
+            ],
+        )
+        kernel = functools.partial(_paged_flat_kernel, scale=scale,
+                                   block_size=block_size, pages=pages)
+        return pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, H, Dv), q.dtype),
+            interpret=interpret,
+        )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), q,
+          k_arena, v_arena)
 
     def kv_map(b, t, tab_ref, len_ref):
         # Clamp dead table slots to the last live block: a repeated block

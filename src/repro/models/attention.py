@@ -19,11 +19,14 @@ Serving additionally supports PAGED caches (vLLM-style): each leaf's
 (B, T) of arena indices says which rows belong to whom. Row 0 of the
 arena is the reserved NULL sink: never allocated, it absorbs writes from
 masked/dead lanes and backs unallocated table entries, so paged updates
-need no per-slot masking. The paged decode/prefill paths gather a
-contiguous per-sequence view and run the *same* attention math as the
-contiguous paths — aligned geometry (``block_size`` dividing the rounded
-``max_len``) makes the views shape- and bit-identical, which is the
-token-equivalence contract the serve tests enforce.
+need no per-slot masking. The paged prefill, verify and MLA decode paths
+gather a contiguous per-sequence view and run the *same* attention math as
+the contiguous paths — aligned geometry (``block_size`` dividing the
+rounded ``max_len``) makes the views shape- and bit-identical, which is
+the token-equivalence contract the serve tests enforce. Paged GQA decode
+does the same off the TPU; lowered for the TPU, where a block is whole
+tiles of the TPU's layout, it runs the Pallas paged flash-decode, which
+reads only each lane's live blocks (``paged_decode_attention``).
 """
 
 from __future__ import annotations
@@ -304,6 +307,53 @@ def decode_attention(
     return out.reshape(B, 1, H, D).astype(q.dtype)
 
 
+def paged_decode_path(n_kv_heads: int, head_dim: int, dtype, platform: str) -> str:
+    """How paged GQA decode over arenas of ``n_kv_heads`` heads of
+    ``head_dim`` in ``dtype`` reads them on a device of ``platform``: the
+    Pallas kernel over live blocks ("paged_kernel") on the TPU, where a
+    block is whole tiles of its layout, else the capacity-sized gathered
+    view ("gather")."""
+    from repro.kernels.decode_attention.kernel import whole_tiles
+
+    fits = whole_tiles(n_kv_heads, head_dim, dtype)
+    return "paged_kernel" if platform == "tpu" and fits else "gather"
+
+
+def _paged_decode_gather(q, ck, cv, block_table, lengths):
+    """Decode attention over the gathered (B, T * block_size) view: the
+    contiguous path's exact arithmetic, so paged and contiguous tokens
+    stay byte-identical."""
+    return decode_attention(q, paged_kv_view(ck, block_table),
+                            paged_kv_view(cv, block_table), length=lengths)
+
+
+def _paged_decode_kernel(q, ck, cv, block_table, lengths, *, interpret=False):
+    """The Pallas paged flash-decode: reads only each lane's live blocks,
+    in the arena's own layout (``kernels/decode_attention``). A lane whose
+    table starts at the NULL block holds no rows (the engine's lanes that
+    are not decoding): it reads nothing and attends to zeros."""
+    from repro.kernels.decode_attention import paged_flash_decode
+
+    lengths = jnp.where(block_table[:, 0] == NULL_BLOCK, 0, lengths)
+    out = paged_flash_decode(q[:, 0], ck, cv, block_table, lengths,
+                             interpret=interpret)
+    return out[:, None]
+
+
+_PAGED_DECODE = {"paged_kernel": _paged_decode_kernel, "gather": _paged_decode_gather}
+
+
+def paged_decode_attention(q, ck, cv, block_table, lengths):
+    """Single-query attention of each lane over its paged KV, read as
+    ``paged_decode_path`` says for the platform the program is lowered
+    for: off the TPU, always the gathered view."""
+    tpu = paged_decode_path(ck.shape[-2], ck.shape[-1], ck.dtype, "tpu")
+    return jax.lax.platform_dependent(
+        q, ck, cv, block_table, lengths,
+        default=_paged_decode_gather, tpu=_PAGED_DECODE[tpu],
+    )
+
+
 def gqa_apply(
     params: Dict,
     x: jax.Array,
@@ -316,8 +366,10 @@ def gqa_apply(
 ) -> Tuple[jax.Array, Optional[Dict]]:
     """Full GQA block. With a cache, runs one-token decode and returns the
     updated cache; without, runs train/prefill chunked attention. With a
-    ``block_table`` the cache leaves are paged arenas; decode attends
-    against the gathered per-sequence view — same math, same bits."""
+    ``block_table`` the cache leaves are paged arenas and decode attends
+    through ``paged_decode_attention``: the live blocks on the TPU where
+    the kernel fits, the gathered per-sequence view (same math, same bits
+    as the contiguous path) elsewhere."""
     q, k, v = _project_qkv(params, x, cfg, positions)
     if cache is None:
         causal = cfg.causal and not cfg.is_encoder
@@ -335,11 +387,11 @@ def gqa_apply(
         idx = cache_index  # int32 write position: scalar or per-row (B,)
         ck = cache_row_update(cache["k"], k, idx, block_table=block_table)
         cv = cache_row_update(cache["v"], v, idx, block_table=block_table)
+        lengths = decode_lengths(idx, x.shape[0])
         if block_table is not None:
-            kv_k, kv_v = paged_kv_view(ck, block_table), paged_kv_view(cv, block_table)
+            out = paged_decode_attention(q, ck, cv, block_table, lengths)
         else:
-            kv_k, kv_v = ck, cv
-        out = decode_attention(q, kv_k, kv_v, length=decode_lengths(idx, x.shape[0]))
+            out = decode_attention(q, ck, cv, length=lengths)
         new_cache = {"k": ck, "v": cv}
     y = jnp.einsum("bshk,hkd->bsd", out, params["wo"])
     return y, new_cache

@@ -38,6 +38,10 @@ __all__ = ["Model", "build_model", "count_params_analytic"]
 # Per-kind decode-step functions (single token, cache threading)
 # ---------------------------------------------------------------------------
 
+#: block kinds whose attention is ``attention.gqa_apply``.
+_GQA_KINDS = ("dense", "parallel", "moe")
+
+
 def _block_decode(
     params: Dict,
     x: jax.Array,
@@ -49,7 +53,7 @@ def _block_decode(
     cache_index: jax.Array,
     block_tables: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict]:
-    if kind in ("dense", "parallel", "moe"):
+    if kind in _GQA_KINDS:
         h = norm_apply(params["attn_norm"], x, cfg.norm)
         a, new_cache = attn.gqa_apply(
             params["attn"], h, cfg, positions=positions,
@@ -92,7 +96,7 @@ def _block_decode(
 
 #: block kinds with a fused multi-token cache-writing prefill. Recurrent
 #: kinds (mlstm/slstm/mamba) prefill through the masked decode scan instead.
-_FUSED_PREFILL_KINDS = ("dense", "parallel", "moe", "mla_dense", "mla_moe")
+_FUSED_PREFILL_KINDS = _GQA_KINDS + ("mla_dense", "mla_moe")
 
 
 def _block_prefill(
@@ -109,7 +113,7 @@ def _block_prefill(
 ) -> Tuple[jax.Array, Dict]:
     """Multi-token block forward that also writes the block's cache rows
     (the serving prefill; mirrors ``_block_decode`` with S > 1)."""
-    if kind in ("dense", "parallel", "moe"):
+    if kind in _GQA_KINDS:
         h = norm_apply(params["attn_norm"], x, cfg.norm)
         a, new_cache = attn.gqa_prefill(
             params["attn"], h, cfg, positions=positions,
@@ -146,7 +150,7 @@ def _block_prefill(
 def _block_cache_specs(
     cfg: ModelConfig, kind: str, batch: int, max_len: int, page=None
 ) -> Optional[Dict]:
-    if kind in ("dense", "parallel", "moe"):
+    if kind in _GQA_KINDS:
         return attn.gqa_cache_spec(cfg, batch, max_len, page)
     if kind in ("mla_dense", "mla_moe"):
         return attn.mla_cache_spec(cfg, batch, max_len, page)
@@ -368,6 +372,12 @@ class Model:
         if self.cfg.family in ("ssm", "hybrid"):
             return False
         return all(seg.kind in _FUSED_PREFILL_KINDS for seg in self.segments)
+
+    @functools.cached_property
+    def gqa_decode(self) -> bool:
+        """True when some block decodes through ``attention.gqa_apply``
+        (whose paged decode is ``attention.paged_decode_attention``)."""
+        return any(seg.kind in _GQA_KINDS for seg in self.segments)
 
     def prefill(self, params: Dict, inputs: jax.Array) -> jax.Array:
         """Prefill forward -> logits for the last position (no cache

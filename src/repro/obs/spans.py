@@ -13,7 +13,8 @@ recorded.
 Keyword arguments become the event's stats (``rid=7``); the event's name
 stays as given. They are evaluated whether or not anything is recorded,
 so pass only values that are already at hand (a step index, a request
-id), never one built for the span.
+id), never one built for the span; the decode tick's ``live_rows``, a
+numpy sum over the slots, is the one exception.
 
 The virtual-clock :class:`~repro.obs.trace.Tracer` stays the
 deterministic replay timeline; wall spans are for where the wall time

@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.attention import NULL_BLOCK
+from repro.models.attention import NULL_BLOCK, paged_decode_path
 from repro.models.layers import ParamSpec, is_paged_spec, slot_mask_select
 from repro.obs import NULL_OBS, Observability, span
 from repro.runtime.steps import (
@@ -289,6 +289,19 @@ class ServeEngine:
             model, n_slots, max_len, block_size,
             0 if self.pool.manager is None else self.pool.manager.num_blocks,
         )
+        #: The decode tick's KV read, the ``kv_path`` of its span: for a
+        #: paged GQA model what ``paged_decode_path`` picks on the pool's
+        #: device, "gather" for other paged models, "contiguous" unpaged.
+        cfg = model.cfg
+        if not self.pool.paged:
+            self._kv_path = "contiguous"
+        elif model.gqa_decode:
+            leaf = jax.tree.leaves(self.pool.caches)[0]
+            self._kv_path = paged_decode_path(
+                cfg.n_kv_heads, cfg.head_dim, cfg.dtype,
+                next(iter(leaf.devices())).platform)
+        else:
+            self._kv_path = "gather"
         # -- speculation (optional) ------------------------------------------
         self.draft: Optional[DraftRunner] = None
         self.spec: Optional[SpecController] = None
@@ -833,7 +846,7 @@ class ServeEngine:
             positions = jnp.asarray(np.clip(pool.positions, 0, pool.max_len - 1))
             logits, pool.caches = self._decode(
                 self.params, tokens, pool.caches, positions, jnp.asarray(mask),
-                pool.tables_device(),
+                pool.tables_device(lanes=mask),
             )
         self.sched.on_decode_tick()
         self.stats.decode_ticks += 1
@@ -1040,7 +1053,10 @@ class ServeEngine:
                     with span("repro.engine.spec"):
                         self._do_spec_round()
                 else:
-                    with span("repro.engine.decode"):
+                    # live_rows: the KV rows the tick attends over.
+                    live = self._decoding
+                    with span("repro.engine.decode", kv_path=self._kv_path,
+                              live_rows=int(self.pool.positions[live].sum() + live.sum())):
                         self._do_decode()
             elif kind == "idle":
                 t0 = self.sched.clock.now

@@ -695,12 +695,18 @@ class SlotPool:
                 )
 
     # -- paged bookkeeping ---------------------------------------------------
-    def tables_device(self, slot: Optional[int] = None) -> Optional[jax.Array]:
+    def tables_device(self, slot: Optional[int] = None, *,
+                      lanes: Optional[np.ndarray] = None) -> Optional[jax.Array]:
         """Block tables as device data — all slots (n_slots, T) for the
-        decode tick, or one (1, T) row for a slot's prefill."""
+        decode tick, or one (1, T) row for a slot's prefill. ``lanes``
+        (n_slots,) bool: the rows of the other slots read NULL, so a
+        tick's writes from lanes that are not decoding fall into the sink
+        and its attention reads none of their blocks."""
         if not self.paged:
             return None
         t = self.manager.tables if slot is None else self.manager.tables[slot:slot + 1]
+        if lanes is not None:
+            t = np.where(lanes[:, None], t, NULL_BLOCK)
         return jnp.asarray(t)
 
     # -- memory accounting (benchmarks) --------------------------------------

@@ -124,6 +124,7 @@ from repro.kernels.decode_attention import (  # noqa: E402
     paged_decode_ref,
     paged_flash_decode,
 )
+from repro.kernels.decode_attention.kernel import PAGES_PER_GROUP  # noqa: E402
 
 DECODE_CASES = [
     # B, S, H, Hkv, D, block_kv
@@ -218,22 +219,31 @@ PAGED_CASES = [
     (64, 8, 2, 64, 16),    # GQA, small blocks
     (128, 8, 1, 64, 32),   # MQA
     (64, 8, 8, 32, 64),    # MHA, one block per sequence
+    (512, 16, 2, 128, 16),  # qwen2.5-3b's heads, block 16: T = 4 groups
+    (256, 8, 1, 128, 16),  # MQA in float32 is whole tiles too: 2 groups
+    (256, 9, 3, 64, 16),   # smollm-135m's heads: the (B, T) grid
 ]
 
 
 @pytest.mark.parametrize("case", PAGED_CASES)
 def test_paged_flash_decode_matches_oracles(case):
     """Kernel vs the jnp paged oracle vs the contiguous oracle across the
-    boundary lengths {0, 1, bs-1, bs, bs+1, max} in one ragged batch.
-    Only live blocks are populated in the arena — everything else is
-    garbage, so any read past a block table's live prefix shows up."""
+    boundary lengths {0, 1, bs-1, bs, bs+1, a group, a group + 1, max} in
+    one ragged batch, and a dead lane (a NULL table, as the engine's free
+    slots have). Only live blocks are populated in the arena — everything
+    else is garbage, so any read past a block table's live prefix shows
+    up; groups past the live ones must be skipped."""
     S, H, Hkv, D, bs = case
-    B = 6
-    lengths = np.array([0, 1, bs - 1, bs, min(bs + 1, S), S], np.int32)
+    group = min(PAGES_PER_GROUP * bs, S)
+    lengths = np.array([0, 1, bs - 1, bs, min(bs + 1, S), group,
+                        min(group + 1, S), S, min(bs + 1, S)], np.int32)
+    B = len(lengths)
     q = _arr((B, H, D), jnp.float32)
     k = _arr((B, S, Hkv, D), jnp.float32)
     v = _arr((B, S, Hkv, D), jnp.float32)
     k_arena, v_arena, tables = _scatter_to_arena(k, v, lengths, bs)
+    live = np.arange(B) != B - 1
+    tables = tables.at[B - 1].set(0)            # dead lane: reads the sink
     lengths = jnp.asarray(lengths)
 
     ref = paged_decode_ref(q, k_arena, v_arena, tables, lengths)
@@ -246,7 +256,7 @@ def test_paged_flash_decode_matches_oracles(case):
     # the length-0 convention rows.
     contig = np.asarray(decode_ref(q, k, v, lengths))
     contig = np.where(np.asarray(lengths)[:, None, None] > 0, contig, 0.0)
-    np.testing.assert_array_equal(np.asarray(ref), contig)
+    np.testing.assert_array_equal(np.asarray(ref)[live], contig[live])
 
 
 def test_paged_flash_decode_ragged_gqa_sweep():

@@ -3,6 +3,7 @@ CPU, output shapes + finiteness; decode/prefill consistency for one arch
 per family; gradient flow."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -182,3 +183,57 @@ def test_pallas_attention_path_refuses_non_tpu_backend():
     toks = jnp.zeros((1, 128), jnp.int32)
     with pytest.raises(ValueError, match="interpret"):
         model.hidden(params, toks, jnp.arange(128))
+
+
+@pytest.mark.parametrize("heads", [(16, 2, 128, "paged_kernel"), (9, 3, 64, "gather")],
+                         ids=["qwen2.5-heads", "smollm-heads"])
+def test_paged_gqa_decode_kernel_matches_gather(monkeypatch, heads):
+    """Paged GQA decode lowered for the TPU runs the Pallas kernel over
+    live blocks where a block is whole tiles of the TPU's layout (qwen2.5's
+    2 kv heads of 128), and the gathered view otherwise (smollm's 3 of 64)
+    and on every other backend. Then the test hands ``gqa_apply`` the
+    interpreted kernel and compares its output and cache writes with the
+    gather path, over lanes that sit at ragged positions across several
+    kernel groups, and a dead lane on a NULL table, which the kernel path
+    skips."""
+    from repro.models import attention as attn
+
+    H, Hkv, D, tpu_path = heads
+    cfg = get_config("smollm-135m").reduced(
+        n_layers=1, d_model=64, n_heads=H, n_kv_heads=Hkv, head_dim=D)
+    params = init_from_specs(RNG, attn.gqa_specs(cfg))
+    B, T, bs = 4, 40, 16
+    rng = np.random.default_rng(0)
+    n_blocks = B * T
+    arena = lambda: jnp.asarray(  # noqa: E731
+        rng.normal(size=(n_blocks + 1, bs, Hkv, D)), jnp.float32)
+    cache = {"k": arena(), "v": arena()}
+    idx = jnp.asarray([0, 17, 16 * 16 + 3, T * bs - 1], jnp.int32)
+    table = np.asarray(rng.permutation(n_blocks) + 1, np.int32).reshape(B, T)
+    table[0] = attn.NULL_BLOCK                  # a dead lane writes to the sink
+    table = jnp.asarray(table)
+    x = jnp.asarray(rng.normal(size=(B, 1, cfg.d_model)), jnp.float32)
+
+    def apply():
+        return attn.gqa_apply(params, x, cfg, positions=idx[:, None], cache=cache,
+                              cache_index=idx, block_table=table)
+
+    y_gather, c_gather = apply()
+    traced = jax.jit(attn.paged_decode_attention).trace(
+        jnp.zeros((B, 1, H, D)), cache["k"], cache["v"], table, idx + 1)
+    for platform, path in ((jax.default_backend(), "gather"), ("tpu", tpu_path)):
+        text = traced.lower(lowering_platforms=(platform,)).as_text()
+        assert ("tpu_custom_call" in text) == (path == "paged_kernel"), platform
+        assert attn.paged_decode_path(Hkv, D, jnp.float32, platform) == path
+
+    monkeypatch.setattr(attn, "paged_decode_attention", functools.partial(
+        attn._paged_decode_kernel, interpret=True))
+    y_kernel, c_kernel = apply()
+    # The dead lane reads nothing on the kernel path (the gather attends
+    # over the sink's garbage); its output is discarded either way.
+    np.testing.assert_array_equal(np.asarray(y_kernel[0]), 0.0)
+    np.testing.assert_allclose(np.asarray(y_kernel[1:]), np.asarray(y_gather[1:]),
+                               atol=2e-5, rtol=2e-5)
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(c_kernel[name]),
+                                      np.asarray(c_gather[name]))

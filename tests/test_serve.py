@@ -336,6 +336,48 @@ def test_paged_pool_commit_append_free_lifecycle():
     mgr.check()
 
 
+def test_paged_decode_tick_leaves_mid_prefill_lane_untouched():
+    """A decode tick writes only for the lanes that are decoding: a lane
+    between two prefill chunks reads a NULL table, so the tick's write
+    for it falls into the sink and its own blocks keep exactly the rows
+    its prefill wrote. The streams stay those of offline decode."""
+    model, params = _model("smollm-135m")
+    eng = ServeEngine(
+        model, params, n_slots=2, max_len=MAX_LEN,
+        scheduler=Scheduler(2, prefill_chunk=8, decode_per_prefill=1),
+        block_size=16,
+    )
+    rng = np.random.default_rng(3)
+    vocab = model.cfg.vocab_size
+    reqs = [(rng.integers(0, vocab, size=5).astype(np.int32), 8),
+            (rng.integers(0, vocab, size=20).astype(np.int32), 4)]
+    rids = [eng.submit(p, m) for p, m in reqs]
+
+    def prefilling_blocks():
+        pool = eng.pool
+        return {
+            slot: [np.asarray(leaf[:, blocks]) for leaf in jax.tree.leaves(pool.caches)]
+            for slot in range(pool.n_slots)
+            if pool.owner[slot] is not None and not eng._decoding[slot]
+            for blocks in [pool.manager.tables[slot][pool.manager.tables[slot] != 0]]
+        }
+
+    ticks = 0
+    while True:
+        before = prefilling_blocks()
+        action = eng.step()
+        if action == "done":
+            break
+        if action == "decode" and before:
+            ticks += 1
+            for slot, leaves in before.items():
+                for old, new in zip(leaves, prefilling_blocks()[slot]):
+                    np.testing.assert_array_equal(old, new)
+    assert ticks > 0, "no tick ran beside a lane mid-prefill; weak test"
+    for rid, (p, m) in zip(rids, reqs):
+        assert eng._requests[rid].tokens == generate_offline(model, params, p, m, MAX_LEN)
+
+
 def test_paged_engine_rejects_oversized_request():
     model, params = _model("smollm-135m")
     eng = ServeEngine(model, params, n_slots=2, max_len=48,

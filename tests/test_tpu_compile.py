@@ -77,6 +77,18 @@ def _paged_flash_decode(spec):
     )
 
 
+def _paged_flash_decode_chat(spec):
+    # The chat cell's serving geometry: qwen2.5-3b's 16/2 heads of 128,
+    # 32 slots of 256 table slots of 16 rows over a 4,096-block arena.
+    slots, table, block, n_blocks, h, hkv, d = 32, 256, 16, 4096, 16, 2, 128
+    arena = (n_blocks + 1, block, hkv, d)
+    return (
+        paged_flash_decode,
+        [spec((slots, h, d)), spec(arena), spec(arena),
+         spec((slots, table), jnp.int32), spec((slots,), jnp.int32)],
+    )
+
+
 def _ssd_scan(spec):
     # Mamba2-130m-like mixer: 24 heads of 64, d_state 128, one group.
     return (
@@ -93,9 +105,10 @@ def _rmsnorm(spec):
 
 @pytest.mark.parametrize(
     "build",
-    [_flash_attention, _flash_decode, _paged_flash_decode, _ssd_scan, _rmsnorm],
-    ids=["flash_attention", "flash_decode", "paged_flash_decode", "ssd_scan",
-         "rmsnorm"],
+    [_flash_attention, _flash_decode, _paged_flash_decode,
+     _paged_flash_decode_chat, _ssd_scan, _rmsnorm],
+    ids=["flash_attention", "flash_decode", "paged_flash_decode",
+         "paged_flash_decode_chat", "ssd_scan", "rmsnorm"],
 )
 def test_kernel_compiles_for_v5e(one_chip, build):
     def spec(shape, dtype=jnp.bfloat16):

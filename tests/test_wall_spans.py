@@ -161,6 +161,9 @@ def test_engine_spans_submit_prefill_decode(tmp_path):
     for d in decodes:
         assert len(_inside(d, spans, "repro.engine.dispatch")) == 1
         assert len(_inside(d, spans, "repro.engine.sync")) == 1
+        # paged GQA off the TPU gathers; 1 to 3 lanes of at most 64 rows
+        assert d[3]["kv_path"] == "gather"
+        assert 1 <= d[3]["live_rows"] <= 3 * 64
     # every action runs inside a step, after that step's schedule span
     for a in prefills + decodes:
         step = [s for s in _named(spans, "repro.engine.step") if a in _inside(s, spans)]
