@@ -37,6 +37,31 @@ class MoEConfig:
     # prompt prefilled as 8+8+8+3 drops different tokens than one 27-token
     # call). Dropless routing is token-local and therefore chunk-invariant.
     dropless: bool = False
+    # Router: the top-k experts' weights are renormalised over the k, times
+    # ``routed_scaling``. "sigmoid" is DeepSeek-V3's ``noaux_tc`` with one
+    # group: the choice ranks the scores plus an (E,) selection bias
+    # (``router_bias``), the weights are the chosen experts' unbiased scores.
+    scoring: str = "softmax"   # softmax | sigmoid
+    routed_scaling: float = 1.0
+    # This chip's share of the experts (expert parallelism): ``n_experts``
+    # stays the published count, the router's width; the layer holds
+    # experts held_offset .. held_offset + n_held - 1 and computes only
+    # their part of the result. None holds them all.
+    n_held: Optional[int] = None
+    held_offset: int = 0
+
+    def __post_init__(self) -> None:
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router scoring {self.scoring}")
+        if not 0 <= self.held_offset < self.held_offset + self.held <= self.n_experts:
+            raise ValueError(
+                f"held experts {self.held_offset}..{self.held_offset + self.held - 1} "
+                f"are not among the {self.n_experts} experts")
+
+    @property
+    def held(self) -> int:
+        """Experts held here (all of them unless ``n_held`` says)."""
+        return self.n_experts if self.n_held is None else self.n_held
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,7 +80,7 @@ class SSMConfig:
 class MLAConfig:
     """DeepSeek multi-head latent attention."""
 
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536   # None: a direct query projection
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -91,6 +116,7 @@ class ModelConfig:
     logit_softcap: float = 0.0
     logit_scale: float = 1.0
     norm: str = "rmsnorm"      # rmsnorm | layernorm
+    rms_norm_eps: float = 1e-6  # epsilon of the blocks' and final RMSNorms
     act: str = "silu"          # silu | gelu
     glu: bool = True           # gated FFN (SwiGLU/GeGLU); False = plain MLP
     tie_embeddings: bool = False
@@ -157,6 +183,9 @@ class ModelConfig:
                 d_expert=64,
                 first_k_dense=min(self.moe.first_k_dense, 1),
                 d_shared=64 if self.moe.n_shared_experts else 0,
+                # a share stays a share: 2 of the 8 experts held
+                n_held=None if self.moe.n_held is None else 2,
+                held_offset=0,
             )
         if self.ssm is not None:
             small["ssm"] = dataclasses.replace(
@@ -164,7 +193,7 @@ class ModelConfig:
             )
         if self.mla is not None:
             small["mla"] = MLAConfig(
-                q_lora_rank=64,
+                q_lora_rank=None if self.mla.q_lora_rank is None else 64,
                 kv_lora_rank=32,
                 qk_nope_head_dim=32,
                 qk_rope_head_dim=16,

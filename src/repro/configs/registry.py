@@ -1,4 +1,4 @@
-"""The 10 assigned architectures, exactly as specified (sources in brackets).
+"""The assigned architectures, exactly as specified (sources in brackets).
 
 Every entry is selectable via ``--arch <id>`` in the launchers and is
 exercised by the dry-run at all applicable shapes.
@@ -106,6 +106,48 @@ def _deepseek_v3() -> ModelConfig:
         ),
         mla_absorb=True,   # latent-space decode = DeepSeek's own deployment
         mtp=True,
+    )
+
+
+def _moonlight_16b() -> ModelConfig:
+    # [moe] 27L d_model=2048 16H MLA without query LoRA, 64 routed experts
+    # of 1408 (top-6, sigmoid scores with a selection bias, weights
+    # renormalised x 2.446) + 2 shared, layer 0 dense (d_ff 11264),
+    # vocab 163840, untied, RMSNorm eps 1e-5
+    # [hf:moonshotai/Moonlight-16B-A3B]. Served as one chip's share of
+    # 8-way expert parallelism: experts 0-7 of each layer held here.
+    return ModelConfig(
+        name="moonlight-16b-a3b",
+        family="moe",
+        n_layers=27,
+        d_model=2048,
+        n_heads=16,
+        n_kv_heads=16,
+        head_dim=192,      # qk_nope(128) + qk_rope(64)
+        d_ff=11264,        # the dense layer
+        vocab_size=163840,
+        rope_theta=50000.0,
+        rms_norm_eps=1e-5,
+        moe=MoEConfig(
+            n_experts=64,
+            top_k=6,
+            d_expert=1408,
+            n_shared_experts=2,
+            first_k_dense=1,
+            dropless=True,
+            scoring="sigmoid",
+            routed_scaling=2.446,
+            n_held=8,
+            held_offset=0,
+        ),
+        mla=MLAConfig(
+            q_lora_rank=None,
+            kv_lora_rank=512,
+            qk_nope_head_dim=128,
+            qk_rope_head_dim=64,
+            v_head_dim=128,
+        ),
+        mla_absorb=True,   # latent-space decode, DeepSeek's own deployment
     )
 
 
@@ -232,6 +274,7 @@ ARCHS: Dict[str, ModelConfig] = {
         _hubert_xlarge(),
         _qwen3_moe_30b(),
         _deepseek_v3(),
+        _moonlight_16b(),
         _llama32_1b(),
         _qwen25_3b(),
         _command_r_35b(),
@@ -247,6 +290,7 @@ ALIASES = {
     "hubert": "hubert-xlarge",
     "qwen3-moe": "qwen3-moe-30b-a3b",
     "deepseek-v3": "deepseek-v3-671b",
+    "moonlight": "moonlight-16b-a3b",
     "llama3.2": "llama3.2-1b",
     "qwen2.5": "qwen2.5-3b",
     "command-r": "command-r-35b",

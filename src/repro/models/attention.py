@@ -463,12 +463,19 @@ def mla_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     d, h = cfg.d_model, cfg.n_heads
     dt = cfg.dtype
     qk_dim = m.qk_nope_head_dim + m.qk_rope_head_dim
+    if m.q_lora_rank is None:
+        # no query LoRA (Moonlight, DeepSeek-V2-Lite): one direct projection
+        q = {"wq": ParamSpec((d, h, qk_dim), ("embed", "heads", "head_dim"), "scaled", dt)}
+    else:
+        q = {
+            "wq_a": ParamSpec((d, m.q_lora_rank), ("embed", "q_lora"), "scaled", dt),
+            "q_norm": norm_specs(m.q_lora_rank, "rmsnorm", dt),
+            "wq_b": ParamSpec(
+                (m.q_lora_rank, h, qk_dim), ("q_lora", "heads", "head_dim"), "scaled", dt
+            ),
+        }
     return {
-        "wq_a": ParamSpec((d, m.q_lora_rank), ("embed", "q_lora"), "scaled", dt),
-        "q_norm": norm_specs(m.q_lora_rank, "rmsnorm", dt),
-        "wq_b": ParamSpec(
-            (m.q_lora_rank, h, qk_dim), ("q_lora", "heads", "head_dim"), "scaled", dt
-        ),
+        **q,
         "wkv_a": ParamSpec(
             (d, m.kv_lora_rank + m.qk_rope_head_dim), ("embed", "kv_lora"), "scaled", dt
         ),
@@ -486,8 +493,11 @@ def mla_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
 def _mla_qkv(params: Dict, x: jax.Array, cfg: ModelConfig, positions: jax.Array):
     m: MLAConfig = cfg.mla
     # Query path.
-    q_lat = norm_apply(params["q_norm"], jnp.einsum("bsd,dr->bsr", x, params["wq_a"]), "rmsnorm")
-    q = jnp.einsum("bsr,rhk->bshk", q_lat, params["wq_b"])
+    if m.q_lora_rank is None:
+        q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
+    else:
+        q_lat = norm_apply(params["q_norm"], jnp.einsum("bsd,dr->bsr", x, params["wq_a"]), "rmsnorm")
+        q = jnp.einsum("bsr,rhk->bshk", q_lat, params["wq_b"])
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
     # Latent KV path.

@@ -269,9 +269,11 @@ def layer_norm(
     return y
 
 
-def norm_apply(params: Dict, x: jax.Array, kind: str) -> jax.Array:
+def norm_apply(params: Dict, x: jax.Array, kind: str, eps: float = 1e-6) -> jax.Array:
+    """``eps`` is RMSNorm's (``ModelConfig.rms_norm_eps``); LayerNorm
+    keeps its own 1e-5."""
     if kind == "rmsnorm":
-        return rms_norm(x, params["scale"])
+        return rms_norm(x, params["scale"], eps)
     return layer_norm(x, params["scale"], params.get("bias"))
 
 

@@ -52,46 +52,47 @@ def _block_decode(
     cache: Dict,
     cache_index: jax.Array,
     block_tables: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Dict]:
+) -> Tuple[jax.Array, Dict, Optional[jax.Array]]:
+    """-> (x, new_cache, rows): ``rows`` (B, S) counts each token's
+    choices that a held expert computed (MoE kinds; None otherwise)."""
     if kind in _GQA_KINDS:
-        h = norm_apply(params["attn_norm"], x, cfg.norm)
+        h = norm_apply(params["attn_norm"], x, cfg.norm, cfg.rms_norm_eps)
         a, new_cache = attn.gqa_apply(
             params["attn"], h, cfg, positions=positions,
             cache=cache, cache_index=cache_index, block_table=block_tables,
         )
         if kind == "parallel":
             f = mlp_apply(params["ffn"], h, cfg.act, cfg.glu)
-            return x + a + f, new_cache
+            return x + a + f, new_cache, None
         x = x + a
-        h = norm_apply(params["mlp_norm"], x, cfg.norm)
-        if kind == "moe":
-            f, _ = moe.moe_apply(params["ffn"], h, cfg)
-        else:
-            f = mlp_apply(params["ffn"], h, cfg.act, cfg.glu)
-        return x + f, new_cache
+        return _ffn(params, x, cfg, kind, new_cache)
     if kind in ("mla_dense", "mla_moe"):
-        h = norm_apply(params["attn_norm"], x, cfg.norm)
+        h = norm_apply(params["attn_norm"], x, cfg.norm, cfg.rms_norm_eps)
         a, new_cache = attn.mla_apply(
             params["attn"], h, cfg, positions=positions,
             cache=cache, cache_index=cache_index, absorb=cfg.mla_absorb,
             block_table=block_tables,
         )
-        x = x + a
-        h = norm_apply(params["mlp_norm"], x, cfg.norm)
-        if kind == "mla_moe":
-            f, _ = moe.moe_apply(params["ffn"], h, cfg)
-        else:
-            f = mlp_apply(params["ffn"], h, cfg.act, cfg.glu)
-        return x + f, new_cache
+        return _ffn(params, x + a, cfg, kind, new_cache)
     if kind == "mlstm":
-        h = norm_apply(params["norm"], x, cfg.norm)
+        h = norm_apply(params["norm"], x, cfg.norm, cfg.rms_norm_eps)
         y, new_state = xlstm.mlstm_decode(params["mixer"], h, cfg, cache)
-        return x + y, new_state
+        return x + y, new_state, None
     if kind == "slstm":
-        h = norm_apply(params["norm"], x, cfg.norm)
+        h = norm_apply(params["norm"], x, cfg.norm, cfg.rms_norm_eps)
         y, new_state = xlstm.slstm_apply(params["mixer"], h, cfg, state=cache)
-        return x + y, new_state
+        return x + y, new_state, None
     raise ValueError(f"no decode for block kind {kind}")
+
+
+def _ffn(params: Dict, x: jax.Array, cfg: ModelConfig, kind: str, new_cache):
+    """The block's pre-norm feed-forward and residual, after attention:
+    -> (x, new_cache, rows) as ``_block_decode`` returns them."""
+    h = norm_apply(params["mlp_norm"], x, cfg.norm, cfg.rms_norm_eps)
+    if kind in ("moe", "mla_moe"):
+        f, _, rows = moe.moe_apply(params["ffn"], h, cfg)
+        return x + f, new_cache, rows
+    return x + mlp_apply(params["ffn"], h, cfg.act, cfg.glu), new_cache, None
 
 
 #: block kinds with a fused multi-token cache-writing prefill. Recurrent
@@ -110,11 +111,11 @@ def _block_prefill(
     start_index: jax.Array,
     block_tables: Optional[jax.Array] = None,
     n_valid: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Dict]:
+) -> Tuple[jax.Array, Dict, Optional[jax.Array]]:
     """Multi-token block forward that also writes the block's cache rows
     (the serving prefill; mirrors ``_block_decode`` with S > 1)."""
     if kind in _GQA_KINDS:
-        h = norm_apply(params["attn_norm"], x, cfg.norm)
+        h = norm_apply(params["attn_norm"], x, cfg.norm, cfg.rms_norm_eps)
         a, new_cache = attn.gqa_prefill(
             params["attn"], h, cfg, positions=positions,
             cache=cache, start_index=start_index, block_table=block_tables,
@@ -122,28 +123,16 @@ def _block_prefill(
         )
         if kind == "parallel":
             f = mlp_apply(params["ffn"], h, cfg.act, cfg.glu)
-            return x + a + f, new_cache
-        x = x + a
-        h = norm_apply(params["mlp_norm"], x, cfg.norm)
-        if kind == "moe":
-            f, _ = moe.moe_apply(params["ffn"], h, cfg)
-        else:
-            f = mlp_apply(params["ffn"], h, cfg.act, cfg.glu)
-        return x + f, new_cache
+            return x + a + f, new_cache, None
+        return _ffn(params, x + a, cfg, kind, new_cache)
     if kind in ("mla_dense", "mla_moe"):
-        h = norm_apply(params["attn_norm"], x, cfg.norm)
+        h = norm_apply(params["attn_norm"], x, cfg.norm, cfg.rms_norm_eps)
         a, new_cache = attn.mla_prefill(
             params["attn"], h, cfg, positions=positions,
             cache=cache, start_index=start_index, block_table=block_tables,
             n_valid=n_valid,
         )
-        x = x + a
-        h = norm_apply(params["mlp_norm"], x, cfg.norm)
-        if kind == "mla_moe":
-            f, _ = moe.moe_apply(params["ffn"], h, cfg)
-        else:
-            f = mlp_apply(params["ffn"], h, cfg.act, cfg.glu)
-        return x + f, new_cache
+        return _ffn(params, x + a, cfg, kind, new_cache)
     raise ValueError(f"no fused prefill for block kind {kind}")
 
 
@@ -249,7 +238,7 @@ class Model:
             h, aux = run_segments(
                 params["stack"], self.segments, x, cfg, positions=positions
             )
-        return norm_apply(params["final_norm"], h, cfg.norm), aux
+        return norm_apply(params["final_norm"], h, cfg.norm, cfg.rms_norm_eps), aux
 
     def logits(self, params: Dict, h: jax.Array) -> jax.Array:
         cfg = self.cfg
@@ -300,7 +289,7 @@ class Model:
         x, _ = block_apply(
             params["mtp"]["block"], x, cfg, self._mtp_kind(), positions=positions
         )
-        x = norm_apply(params["mtp"]["norm"], x, cfg.norm)
+        x = norm_apply(params["mtp"]["norm"], x, cfg.norm, cfg.rms_norm_eps)
         logits2 = self.logits(params, x)
         labels2 = jnp.roll(labels, -1, axis=1)
         mask2 = mask * (jnp.arange(labels.shape[1]) < labels.shape[1] - 1)
@@ -396,6 +385,7 @@ class Model:
         length: Optional[jax.Array] = None,    # (B,) valid tokens per row
         start_index: jax.Array = 0,            # scalar: first write position
         block_tables: Optional[jax.Array] = None,  # (B, T) paged arenas
+        moe_rows: bool = False,
     ):
         """Batched cache-writing prefill -> (last-valid logits (B,1,V), caches).
 
@@ -407,7 +397,11 @@ class Model:
         masking so pad tokens never touch the state. ``start_index > 0``
         continues a partially prefilled cache (chunked prefill). With
         ``block_tables`` the sequence-axis cache leaves are paged arenas
-        and the chunk's rows are written as bulk block scatters."""
+        and the chunk's rows are written as bulk block scatters.
+
+        ``moe_rows=True`` (an MoE stack) adds a third result, (B,): the
+        choices of each row's real tokens that held experts computed,
+        summed over the MoE layers."""
         cfg = self.cfg
         B, P = inputs.shape
         start_index = jnp.asarray(start_index, jnp.int32)
@@ -416,13 +410,19 @@ class Model:
 
         if self.fused_prefill:
             positions = start_index + jnp.arange(P)
-            h, new_caches = self._fused_prefill_stack(
+            h, new_caches, rows = self._fused_prefill_stack(
                 params, inputs, caches, positions=positions,
                 start_index=start_index, block_tables=block_tables,
             )
             last = jnp.clip(length - 1, 0, P - 1)
             h_last = jnp.take_along_axis(h, last[:, None, None], axis=1)
+            if moe_rows:
+                live = jnp.arange(P)[None, :] < length[:, None]
+                return (self.logits(params, h_last), new_caches,
+                        jnp.where(live, rows, 0).sum(-1))
             return self.logits(params, h_last), new_caches
+        if moe_rows:
+            raise ValueError("moe_rows needs a fused (attention) prefill")
 
         # Recurrent/hybrid fallback: scan the decode step over the chunk,
         # masking cache updates (and the returned logits) past each row's
@@ -470,13 +470,13 @@ class Model:
         those two never diverging in how they traverse the stack."""
         cfg = self.cfg
         x = self.embed_inputs(params, inputs)
-        new_caches = []
+        new_caches, rows = [], []
         h = x
         for seg_params, seg_cache, seg in zip(
             params["stack"], caches, self.segments
         ):
             if seg.count == 1:
-                h, nc = _block_prefill(
+                h, nc, r = _block_prefill(
                     seg_params, h, cfg, seg.kind, positions=positions,
                     cache=seg_cache, start_index=start_index,
                     block_tables=block_tables, n_valid=n_valid,
@@ -484,15 +484,18 @@ class Model:
             else:
                 def scan_fn(carry, xs):
                     layer, cache = xs
-                    h2, nc = _block_prefill(
+                    h2, nc, r = _block_prefill(
                         layer, carry, cfg, seg.kind, positions=positions,
                         cache=cache, start_index=start_index,
                         block_tables=block_tables, n_valid=n_valid,
                     )
-                    return h2, nc
-                h, nc = jax.lax.scan(scan_fn, h, (seg_params, seg_cache))
+                    return h2, (nc, r)
+                h, (nc, r) = jax.lax.scan(scan_fn, h, (seg_params, seg_cache))
+                r = None if r is None else r.sum(0)
             new_caches.append(nc)
-        return norm_apply(params["final_norm"], h, cfg.norm), new_caches
+            rows.append(r)
+        h = norm_apply(params["final_norm"], h, cfg.norm, cfg.rms_norm_eps)
+        return h, new_caches, _layer_sum(rows)
 
     def verify_with_cache(
         self,
@@ -540,7 +543,7 @@ class Model:
 
         if self.fused_prefill:
             positions = start[:, None] + jnp.arange(S)   # (B, S) rope positions
-            h, new_caches = self._fused_prefill_stack(
+            h, new_caches, _ = self._fused_prefill_stack(
                 params, inputs, caches, positions=positions,
                 start_index=start, block_tables=block_tables, n_valid=n_input,
             )
@@ -584,7 +587,11 @@ class Model:
         caches,
         cache_index: jax.Array,    # int32 current length: scalar or (B,)
         block_tables: Optional[jax.Array] = None,  # (B, T): paged KV arenas
+        moe_rows: bool = False,
     ):
+        """-> (logits (B, 1, V), caches); ``moe_rows=True`` (an MoE stack)
+        adds (B,): each lane's choices that held experts computed, summed
+        over the MoE layers."""
         cfg = self.cfg
         x = params["embed"][token]
         idx = jnp.asarray(cache_index, jnp.int32)
@@ -599,12 +606,13 @@ class Model:
                 positions=positions, cache_index=cache_index,
                 block_tables=block_tables,
             )
+            rows = [None]
         else:
-            new_caches = []
+            new_caches, rows = [], []
             h = x
             for seg_params, seg_cache, seg in zip(params["stack"], caches, self.segments):
                 if seg.count == 1:
-                    h, nc = _block_decode(
+                    h, nc, r = _block_decode(
                         seg_params, h, cfg, seg.kind,
                         positions=positions, cache=seg_cache, cache_index=cache_index,
                         block_tables=block_tables,
@@ -612,15 +620,19 @@ class Model:
                 else:
                     def scan_fn(carry, xs):
                         layer, cache = xs
-                        h2, nc = _block_decode(
+                        h2, nc, r = _block_decode(
                             layer, carry, cfg, seg.kind,
                             positions=positions, cache=cache, cache_index=cache_index,
                             block_tables=block_tables,
                         )
-                        return h2, nc
-                    h, nc = jax.lax.scan(scan_fn, h, (seg_params, seg_cache))
+                        return h2, (nc, r)
+                    h, (nc, r) = jax.lax.scan(scan_fn, h, (seg_params, seg_cache))
+                    r = None if r is None else r.sum(0)
                 new_caches.append(nc)
-        h = norm_apply(params["final_norm"], h, cfg.norm)
+                rows.append(r)
+        h = norm_apply(params["final_norm"], h, cfg.norm, cfg.rms_norm_eps)
+        if moe_rows:
+            return self.logits(params, h), new_caches, _layer_sum(rows).sum(-1)
         return self.logits(params, h), new_caches
 
 
@@ -628,16 +640,25 @@ def build_model(cfg: ModelConfig) -> Model:
     return Model(cfg)
 
 
+def _layer_sum(rows: List[Optional[jax.Array]]) -> Optional[jax.Array]:
+    """Sum of the segments' per-token expert rows (None: no MoE layer)."""
+    rows = [r for r in rows if r is not None]
+    return sum(rows[1:], rows[0]) if rows else None
+
+
 def count_params_analytic(cfg: ModelConfig, active_only: bool = False) -> int:
-    """Parameter count from the spec tree (exact). active_only: count each
-    MoE layer as top_k (+shared) experts instead of all experts."""
+    """Parameter count from the spec tree (exact): the experts held (the
+    tree holds only a share's). active_only: count each MoE layer's held
+    experts at the number a token reaches on average, top_k x held /
+    n_experts (top_k when all are held), plus the shared experts."""
     model = build_model(cfg)
     total = count_specs(model.param_specs())
     if active_only and cfg.moe is not None:
-        d, de = cfg.d_model, cfg.moe.d_expert
-        per_expert = 3 * d * de
-        n_moe_layers = cfg.n_layers - cfg.moe.first_k_dense
-        total -= (cfg.moe.n_experts - cfg.moe.top_k) * per_expert * n_moe_layers
+        moe = cfg.moe
+        per_expert = 3 * cfg.d_model * moe.d_expert
+        n_moe_layers = cfg.n_layers - moe.first_k_dense
+        active = moe.top_k * moe.held / moe.n_experts
+        total -= round((moe.held - active) * per_expert * n_moe_layers)
     return total
 
 

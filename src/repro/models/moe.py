@@ -11,7 +11,19 @@ Faithfulness notes: token-choice top-k routing with softmax gates
 (renormalized over the top-k), optional DeepSeek-style shared experts and
 leading dense layers, capacity dropping with zero-fill (dropped tokens
 pass through the residual stream only), and the standard load-balance
-auxiliary loss (Switch/GShard form).
+auxiliary loss (Switch/GShard form). DeepSeek-V3's router
+(``MoEConfig.scoring="sigmoid"``): sigmoid scores in float32, the top-k
+chosen on the scores plus a selection bias, the weights the chosen
+unbiased scores renormalized and scaled; its sequence-wise auxiliary
+loss is not built (the Switch form stands in, on the scores normalized
+per token).
+
+Expert share (``MoEConfig.n_held``/``held_offset``, expert parallelism):
+the router keeps all ``n_experts`` outputs and picks its top-k over all
+of them; the layer holds only its experts' weights, dispatches only the
+choices that fall on them, and returns their weighted part plus the
+shared experts' whole output. The absent experts' part is another
+chip's; on one chip there is no exchange.
 """
 
 from __future__ import annotations
@@ -39,21 +51,29 @@ def moe_capacity(moe: MoEConfig, tokens: int) -> int:
 
 
 def moe_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
+    """The router at the published width (d, n_experts); the expert
+    stacks of the held experts only (held, d, d_expert). The selection
+    bias (``router_bias``, float32 as DeepSeek-V3 keeps it) enters only
+    the choice of experts, so it has no gradient: training leaves it as
+    initialised (DeepSeek-V3 moves it by a balance rule outside the
+    gradient, which this repo does not run)."""
     moe = cfg.moe
     d, dt = cfg.d_model, cfg.dtype
-    de = moe.d_expert
+    de, eh = moe.d_expert, moe.held
     specs: Dict[str, ParamSpec] = {
         "router": ParamSpec((d, moe.n_experts), ("embed", None), "scaled", dt),
         "w_in": ParamSpec(
-            (moe.n_experts, d, de), ("expert", "embed", "expert_ffn"), "scaled", dt
+            (eh, d, de), ("expert", "embed", "expert_ffn"), "scaled", dt
         ),
         "w_gate": ParamSpec(
-            (moe.n_experts, d, de), ("expert", "embed", "expert_ffn"), "scaled", dt
+            (eh, d, de), ("expert", "embed", "expert_ffn"), "scaled", dt
         ),
         "w_out": ParamSpec(
-            (moe.n_experts, de, d), ("expert", "expert_ffn", "embed"), "scaled", dt
+            (eh, de, d), ("expert", "expert_ffn", "embed"), "scaled", dt
         ),
     }
+    if moe.scoring == "sigmoid":
+        specs["router_bias"] = ParamSpec((moe.n_experts,), (None,), "normal", "float32")
     if moe.n_shared_experts > 0:
         d_sh = (moe.d_shared or moe.d_expert) * moe.n_shared_experts
         specs["shared"] = mlp_specs(d, d_sh, glu=True, dtype=dt)
@@ -61,13 +81,28 @@ def moe_specs(cfg: ModelConfig) -> Dict[str, ParamSpec]:
 
 
 def _route(
-    x_flat: jax.Array, router: jax.Array, moe: MoEConfig
+    x_flat: jax.Array, params: Dict, moe: MoEConfig
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Returns (weights (T,K), experts (T,K) int32, aux_loss scalar)."""
-    logits = jnp.einsum("td,de->te", x_flat, router).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, moe.top_k)
+    """Returns (weights (T,K), experts (T,K) int32, aux_loss scalar);
+    ``experts`` index all ``n_experts``, held or not."""
+    router = params["router"]
+    if moe.scoring == "sigmoid":
+        f32 = jnp.float32
+        logits = jnp.einsum("td,de->te", x_flat.astype(f32), router.astype(f32))
+        scores = jax.nn.sigmoid(logits)
+        probs = scores / scores.sum(-1, keepdims=True)
+    else:
+        logits = jnp.einsum("td,de->te", x_flat, router).astype(jnp.float32)
+        scores = probs = jax.nn.softmax(logits, axis=-1)
+    if moe.scoring == "sigmoid":
+        bias = jax.lax.stop_gradient(params["router_bias"]).astype(jnp.float32)
+        _, experts = jax.lax.top_k(scores + bias, moe.top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+    else:
+        weights, experts = jax.lax.top_k(scores, moe.top_k)
     weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    if moe.routed_scaling != 1.0:
+        weights = weights * moe.routed_scaling
     # Load-balance aux loss: E * sum_e f_e * P_e  (Switch Transformer eq. 4).
     E = moe.n_experts
     f = jnp.zeros((E,), jnp.float32).at[experts.reshape(-1)].add(1.0)
@@ -93,8 +128,9 @@ def _dp_group_count(T: int) -> int:
 
 def moe_apply(
     params: Dict, x: jax.Array, cfg: ModelConfig
-) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, D) -> (out (B, S, D), aux_loss scalar).
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (B, S, D) -> (out (B, S, D), aux_loss scalar, rows (B, S)
+    int32: how many of each token's choices a held expert computed).
 
     Dispatch formulations (cfg.moe.dispatch — §Perf iterations):
       "data"    : dispatched tokens stay batch-sharded; scatter into the
@@ -107,6 +143,8 @@ def moe_apply(
                   partition the 2-axis scatter; kept for the record).
     """
     if cfg.moe.dispatch == "grouped":
+        if cfg.moe.held != cfg.moe.n_experts:
+            raise NotImplementedError("grouped dispatch holds every expert")
         return _moe_apply_grouped(params, x, cfg)
     return _moe_apply_flat(params, x, cfg)
 
@@ -115,20 +153,28 @@ def _moe_apply_flat(params, x, cfg):
     moe = cfg.moe
     B, S, D = x.shape
     T = B * S
-    K, E = moe.top_k, moe.n_experts
+    K, E = moe.top_k, moe.held
     C = moe_capacity(moe, T)
     x_flat = x.reshape(T, D)
 
-    weights, experts, aux = _route(x_flat, params["router"], moe)
+    weights, experts, aux = _route(x_flat, params, moe)
 
     flat_e = experts.reshape(-1)                       # (T*K,)
+    if E != moe.n_experts:
+        # held experts by local index; a choice of an absent expert goes
+        # to bucket E, which no buffer holds
+        flat_e = flat_e - moe.held_offset
+        flat_e = jnp.where((flat_e >= 0) & (flat_e < E), flat_e, E)
+    n_buckets = E + (E != moe.n_experts)
     order = jnp.argsort(flat_e, stable=True)
     sorted_e = flat_e[order]
-    counts = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
+    counts = jnp.zeros((n_buckets,), jnp.int32).at[flat_e].add(1)
     starts = jnp.cumsum(counts) - counts
     rank_sorted = jnp.arange(T * K, dtype=jnp.int32) - starts[sorted_e]
     pos = jnp.zeros((T * K,), jnp.int32).at[order].set(rank_sorted)
     keep = pos < C
+    if E != moe.n_experts:
+        keep = keep & (flat_e < E)
 
     token_idx = jnp.repeat(jnp.arange(T, dtype=jnp.int32), K)
     safe_e = jnp.where(keep, flat_e, 0)
@@ -156,7 +202,8 @@ def _moe_apply_flat(params, x, cfg):
     if moe.n_shared_experts > 0:
         out = out + mlp_apply(params["shared"], x_flat, cfg.act, glu=True)
 
-    return out.reshape(B, S, D), aux.astype(jnp.float32)
+    rows = keep.reshape(T, K).sum(-1, dtype=jnp.int32).reshape(B, S)
+    return out.reshape(B, S, D), aux.astype(jnp.float32), rows
 
 
 def _moe_apply_grouped(params, x, cfg):
@@ -169,7 +216,7 @@ def _moe_apply_grouped(params, x, cfg):
     C = max(moe_capacity(moe, T) // G, K)
     x_flat = x.reshape(T, D)
 
-    weights, experts, aux = _route(x_flat, params["router"], moe)
+    weights, experts, aux = _route(x_flat, params, moe)
 
     # Rank each (token, choice) within its (group, expert) bucket.
     eg = experts.reshape(G, Tg * K)                     # (G, Tg*K)
@@ -226,4 +273,5 @@ def _moe_apply_grouped(params, x, cfg):
     if moe.n_shared_experts > 0:
         out = out + mlp_apply(params["shared"], x_flat, cfg.act, glu=True)
 
-    return out.reshape(B, S, D), aux.astype(jnp.float32)
+    rows = keep.reshape(T, K).sum(-1, dtype=jnp.int32).reshape(B, S)
+    return out.reshape(B, S, D), aux.astype(jnp.float32), rows

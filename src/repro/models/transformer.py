@@ -148,31 +148,31 @@ def block_apply(
     """Returns (x_out, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     if kind in ("dense", "encoder", "moe", "mla_dense", "mla_moe"):
-        h = norm_apply(params["attn_norm"], x, cfg.norm)
+        h = norm_apply(params["attn_norm"], x, cfg.norm, cfg.rms_norm_eps)
         if kind.startswith("mla"):
             a, _ = attn.mla_apply(params["attn"], h, cfg, positions=positions)
         else:
             a, _ = attn.gqa_apply(params["attn"], h, cfg, positions=positions)
         x = x + a
-        h = norm_apply(params["mlp_norm"], x, cfg.norm)
+        h = norm_apply(params["mlp_norm"], x, cfg.norm, cfg.rms_norm_eps)
         if kind in ("moe", "mla_moe"):
-            f, aux = moe.moe_apply(params["ffn"], h, cfg)
+            f, aux, _ = moe.moe_apply(params["ffn"], h, cfg)
         else:
             f = mlp_apply(params["ffn"], h, cfg.act, cfg.glu)
         return x + f, aux
     if kind == "parallel":
-        h = norm_apply(params["attn_norm"], x, cfg.norm)
+        h = norm_apply(params["attn_norm"], x, cfg.norm, cfg.rms_norm_eps)
         a, _ = attn.gqa_apply(params["attn"], h, cfg, positions=positions)
         f = mlp_apply(params["ffn"], h, cfg.act, cfg.glu)
         return x + a + f, aux
     if kind == "mamba":
-        h = norm_apply(params["norm"], x, cfg.norm)
+        h = norm_apply(params["norm"], x, cfg.norm, cfg.rms_norm_eps)
         return x + mamba2.mamba2_apply(params["mixer"], h, cfg), aux
     if kind == "mlstm":
-        h = norm_apply(params["norm"], x, cfg.norm)
+        h = norm_apply(params["norm"], x, cfg.norm, cfg.rms_norm_eps)
         return x + xlstm.mlstm_apply(params["mixer"], h, cfg), aux
     if kind == "slstm":
-        h = norm_apply(params["norm"], x, cfg.norm)
+        h = norm_apply(params["norm"], x, cfg.norm, cfg.rms_norm_eps)
         y, _ = xlstm.slstm_apply(params["mixer"], h, cfg)
         return x + y, aux
     raise ValueError(f"unknown block kind {kind}")

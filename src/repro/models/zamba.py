@@ -103,7 +103,7 @@ def _shared_block(
     dw = _shared_width(cfg)
     H, hd = cfg.n_heads, dw // cfg.n_heads
     t = jnp.concatenate([h, x0], axis=-1)
-    tn = norm_apply(params["norm"], t, cfg.norm)
+    tn = norm_apply(params["norm"], t, cfg.norm, cfg.rms_norm_eps)
 
     qkv = jnp.einsum("bsd,dchk->bschk", tn, params["wqkv"])
     lora_in = jnp.einsum("bsd,dr->bsr", tn, lora["qkv_a"])
@@ -131,7 +131,7 @@ def _shared_block(
         new_cache = {"k": ck, "v": cv}
     t = t + jnp.einsum("bshk,hkd->bsd", o, params["wo"])
 
-    tn = norm_apply(params["mlp_norm"], t, cfg.norm)
+    tn = norm_apply(params["mlp_norm"], t, cfg.norm, cfg.rms_norm_eps)
     gate = jnp.einsum("bsd,df->bsf", tn, params["w_gate"])
     up = jnp.einsum("bsd,df->bsf", tn, params["w_in"])
     up = up + jnp.einsum(
@@ -165,7 +165,7 @@ def zamba_apply(
     rem = cfg.n_layers - groups * ae
 
     def mamba_block(layer, h):
-        hn = norm_apply(layer["norm"], h, cfg.norm)
+        hn = norm_apply(layer["norm"], h, cfg.norm, cfg.rms_norm_eps)
         return constrain_batch(h + mamba2.mamba2_apply(layer["mixer"], hn, cfg))
 
     def shared_block(shared, lora, h):
@@ -219,7 +219,7 @@ def zamba_decode(
     for i in range(cfg.n_layers):
         layer = jax.tree.map(lambda t: t[i], params["mamba"])
         state = jax.tree.map(lambda t: t[i], caches["mamba"])
-        hn = norm_apply(layer["norm"], h, cfg.norm)
+        hn = norm_apply(layer["norm"], h, cfg.norm, cfg.rms_norm_eps)
         delta, new_state = mamba2.mamba2_decode(layer["mixer"], hn, cfg, state)
         h = h + delta
         new_mamba_states.append(new_state)

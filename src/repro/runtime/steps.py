@@ -189,7 +189,7 @@ def make_prefill_step(model: Model) -> Callable:
     return prefill_step
 
 
-def make_slot_prefill_step(model: Model) -> Callable:
+def make_slot_prefill_step(model: Model, moe_rows: bool = False) -> Callable:
     """Cache-writing batched prefill for the serving engine.
 
     (params, inputs (B,P) right-padded, caches, length (B,), start_index,
@@ -197,14 +197,17 @@ def make_slot_prefill_step(model: Model) -> Callable:
     fastest-k ``worker_mask``, the ragged-length information enters as
     DATA — one compile per (B, P-bucket) shape, re-used across every
     admission. ``block_tables`` (B, T) routes the chunk's cache rows
-    through paged arenas (None = contiguous slot stripes)."""
+    through paged arenas (None = contiguous slot stripes). ``moe_rows``
+    adds a third result: the held experts' rows the chunk's real tokens
+    took, summed (``Model.prefill_with_cache``)."""
 
     def slot_prefill_step(params, inputs, caches, length, start_index,
                           block_tables=None):
-        return model.prefill_with_cache(
+        out = model.prefill_with_cache(
             params, inputs, caches, length=length, start_index=start_index,
-            block_tables=block_tables,
+            block_tables=block_tables, moe_rows=moe_rows,
         )
+        return (*out[:2], out[2].sum()) if moe_rows else out
 
     return slot_prefill_step
 
@@ -216,17 +219,19 @@ def make_decode_step(model: Model) -> Callable:
     return decode_step
 
 
-def make_slot_decode_step(model: Model) -> Callable:
+def make_slot_decode_step(model: Model, moe_rows: bool = False) -> Callable:
     """One decode tick over the whole slot pool.
 
     ``cache_index`` is the per-slot position vector (n_slots,) — every
     slot sits at its own length; free slots ride along as masked lanes
     (their writes land in dead rows and are overwritten at allocation),
-    so occupancy never changes the compiled shape."""
+    so occupancy never changes the compiled shape. ``moe_rows`` adds a
+    third result, each lane's held-expert rows (``Model.decode_step``)."""
 
     def slot_decode_step(params, tokens, caches, cache_index, block_tables=None):
         return model.decode_step(
-            params, tokens, caches, cache_index, block_tables=block_tables
+            params, tokens, caches, cache_index, block_tables=block_tables,
+            moe_rows=moe_rows,
         )
 
     return slot_decode_step

@@ -53,6 +53,7 @@ import numpy as np
 
 from repro.models.attention import NULL_BLOCK, paged_decode_path
 from repro.models.layers import ParamSpec, is_paged_spec, slot_mask_select
+from repro.models.moe import moe_capacity
 from repro.obs import NULL_OBS, Observability, span
 from repro.runtime.steps import (
     make_slot_decode_step,
@@ -142,6 +143,8 @@ class EngineStats:
     prefix_rows_shared: int = 0   # cache rows skipped via adoption
     migrated_out: int = 0         # requests exported as MigrationTickets
     migrated_in: int = 0          # tickets restored into this engine
+    moe_rows: int = 0             # held experts' rows that real tokens took
+    moe_rows_computed: int = 0    # held experts x capacity x MoE layers
     virtual_seconds: float = 0.0
 
     @property
@@ -160,16 +163,22 @@ def _engine_steps(model, n_slots: int, max_len: int,
     specs = model.cache_specs(
         n_slots, max_len, block_size=block_size, num_blocks=arena_blocks
     )
-    prefill = make_slot_prefill_step(model)
-    decode = make_slot_decode_step(model)
+    # MoE stacks (all fused) count their held experts' rows on the device
+    moe_rows = model.cfg.moe is not None and model.fused_prefill
+    prefill = make_slot_prefill_step(model, moe_rows)
+    decode = make_slot_decode_step(model, moe_rows)
 
     def decode_tick(params, tokens, caches, positions, mask, tables=None):
-        logits, new_caches = decode(params, tokens, caches, positions, tables)
+        logits, new_caches, *rows = decode(params, tokens, caches, positions, tables)
         # Lanes not decoding (free / mid-prefill) must not mutate
         # state: recurrent leaves would otherwise absorb garbage.
         # (Paged leaves skip the select — dead-lane writes went to the
         # NULL sink block via their zeroed block tables.)
-        return logits, slot_mask_select(mask, new_caches, caches, specs)
+        new_caches = slot_mask_select(mask, new_caches, caches, specs)
+        if rows:
+            # the held experts' rows of the lanes that decode
+            return logits, new_caches, jnp.where(mask, rows[0], 0).sum()
+        return logits, new_caches
 
     # Speculative verify (only traced when an engine actually has a
     # draft model — jax.jit is lazy). Needs no extra masking: dead-lane
@@ -291,7 +300,8 @@ class ServeEngine:
         )
         #: The decode tick's KV read, the ``kv_path`` of its span: for a
         #: paged GQA model what ``paged_decode_path`` picks on the pool's
-        #: device, "gather" for other paged models, "contiguous" unpaged.
+        #: device, "latent_gather" for paged MLA (the gathered latent
+        #: view), "gather" for other paged models, "contiguous" unpaged.
         cfg = model.cfg
         if not self.pool.paged:
             self._kv_path = "contiguous"
@@ -300,8 +310,14 @@ class ServeEngine:
             self._kv_path = paged_decode_path(
                 cfg.n_kv_heads, cfg.head_dim, cfg.dtype,
                 next(iter(leaf.devices())).platform)
+        elif cfg.mla is not None:
+            self._kv_path = "latent_gather"
         else:
             self._kv_path = "gather"
+        #: MoE: each step's held-expert rows (a device scalar) wait here
+        #: until the next read of tokens carries them back.
+        self._moe_pending: List[jax.Array] = []
+        self._moe_computed = 0
         # -- speculation (optional) ------------------------------------------
         self.draft: Optional[DraftRunner] = None
         self.spec: Optional[SpecController] = None
@@ -666,7 +682,7 @@ class ServeEngine:
         slot_caches = (self._fresh_slot_caches() if first
                        else pool.read_slot(slot))
         with span("repro.engine.dispatch"):
-            logits, slot_caches = self._prefill(
+            logits, slot_caches, *rows = self._prefill(
                 self.params,
                 jnp.asarray(chunk),
                 slot_caches,
@@ -674,6 +690,7 @@ class ServeEngine:
                 jnp.int32(start),
                 pool.tables_device(slot),
             )
+        self._moe_step(rows, bucket)
         pool.write_slot(slot, slot_caches, position=start + n_tok)
         if self.speculative:
             # The draft cache must hold the same prefix (same bucketed
@@ -698,7 +715,7 @@ class ServeEngine:
                 self._decoding[slot] = True
             else:
                 with span("repro.engine.sync"):
-                    tok = int(jnp.argmax(logits[0, -1]))
+                    tok = int(self._read(jnp.argmax(logits[0, -1])))
                 self._emit(req, tok)
                 if self._finished(req):     # max_new_tokens == 1
                     self._free_slot(slot)
@@ -713,6 +730,32 @@ class ServeEngine:
                 args={"rid": req.rid, "start": start,
                       "n_tokens": n_tok, "done": done},
             )
+
+    def _moe_step(self, rows: List[jax.Array], tokens: int) -> None:
+        """Hold a step's held-expert rows (``rows``: [] or its one device
+        scalar) for the next read; count what its ``tokens``-token call
+        computed: held experts x capacity x MoE layers."""
+        if rows:
+            moe = self.model.cfg.moe
+            self._moe_pending.append(rows[0])
+            self._moe_computed += (moe.held * moe_capacity(moe, tokens)
+                                   * (self.model.cfg.n_layers - moe.first_k_dense))
+
+    def _read(self, tokens: jax.Array) -> np.ndarray:
+        """Bring a step's tokens to the host, and in the same transfer the
+        held-expert rows of the steps since the last read; these land in
+        ``stats`` and in a ``repro.engine.moe`` span (``moe_rows=``,
+        ``moe_rows_computed=``)."""
+        if not self._moe_pending:
+            return np.asarray(tokens)
+        tokens, rows = jax.device_get((tokens, self._moe_pending))
+        moe_rows, computed = int(sum(rows)), self._moe_computed
+        self._moe_pending, self._moe_computed = [], 0
+        self.stats.moe_rows += moe_rows
+        self.stats.moe_rows_computed += computed
+        with span("repro.engine.moe", moe_rows=moe_rows, moe_rows_computed=computed):
+            pass
+        return np.asarray(tokens)
 
     def _free_slot(self, slot: int) -> None:
         self.pool.free(slot)
@@ -844,14 +887,16 @@ class ServeEngine:
         with span("repro.engine.dispatch"):
             tokens = jnp.asarray(self._pending[:, None])
             positions = jnp.asarray(np.clip(pool.positions, 0, pool.max_len - 1))
-            logits, pool.caches = self._decode(
+            logits, pool.caches, *rows = self._decode(
                 self.params, tokens, pool.caches, positions, jnp.asarray(mask),
                 pool.tables_device(lanes=mask),
             )
+        self._moe_step(rows, pool.n_slots)
         self.sched.on_decode_tick()
         self.stats.decode_ticks += 1
         with span("repro.engine.sync"):
-            next_tok = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1), np.int32)
+            next_tok = np.asarray(self._read(jnp.argmax(logits[:, -1, :], axis=-1)),
+                                  np.int32)
         for slot in np.nonzero(mask)[0]:
             slot = int(slot)
             pool.positions[slot] += 1
