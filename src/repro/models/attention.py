@@ -19,14 +19,14 @@ Serving additionally supports PAGED caches (vLLM-style): each leaf's
 (B, T) of arena indices says which rows belong to whom. Row 0 of the
 arena is the reserved NULL sink: never allocated, it absorbs writes from
 masked/dead lanes and backs unallocated table entries, so paged updates
-need no per-slot masking. The paged prefill, verify and MLA decode paths
-gather a contiguous per-sequence view and run the *same* attention math as
-the contiguous paths — aligned geometry (``block_size`` dividing the
-rounded ``max_len``) makes the views shape- and bit-identical, which is
-the token-equivalence contract the serve tests enforce. Paged GQA decode
-does the same off the TPU; lowered for the TPU, where a block is whole
-tiles of the TPU's layout, it runs the Pallas paged flash-decode, which
-reads only each lane's live blocks (``paged_decode_attention``).
+need no per-slot masking. The paged prefill and verify paths gather a
+contiguous per-sequence view and run the *same* attention math as the
+contiguous paths — aligned geometry (``block_size`` dividing the rounded
+``max_len``) makes the views shape- and bit-identical, which is the
+token-equivalence contract the serve tests enforce. Paged decode does the
+same off the TPU; lowered for the TPU, where a block is whole tiles of the
+TPU's layout, it runs a Pallas kernel over each lane's live blocks only
+(``paged_decode_attention`` for GQA, ``paged_latent_attention`` for MLA).
 """
 
 from __future__ import annotations
@@ -515,6 +515,75 @@ def _mla_expand_kv(params: Dict, ckv: jax.Array, cfg: ModelConfig):
     return k_nope, v
 
 
+def latent_decode_attention(q_lat, q_rope, c_ckv, c_rope, length, *, scale):
+    """MLA's absorbed single-query attention over a contiguous latent view:
+    q_lat (B, 1, H, r) and q_rope (B, 1, H, rope) against c_ckv (B, S, r)
+    and c_rope (B, S, rope), rows past ``length`` (B,) masked -> o_lat
+    (B, 1, H, r) in float32."""
+    S = c_ckv.shape[1]
+    pos_mask = jnp.arange(S)[None, :] < length[:, None]
+    s = (
+        jnp.einsum("bshr,btr->bhst", q_lat.astype(jnp.float32), c_ckv.astype(jnp.float32))
+        + jnp.einsum("bshk,btk->bhst", q_rope.astype(jnp.float32), c_rope.astype(jnp.float32))
+    ) * scale
+    s = jnp.where(pos_mask[:, None, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhst,btr->bshr", p, c_ckv.astype(jnp.float32))
+
+
+def paged_latent_path(kv_lora_rank: int, dtype, block_size: int, platform: str) -> str:
+    """How absorbed MLA decode over paged latent arenas of rank
+    ``kv_lora_rank`` in ``dtype``, in blocks of ``block_size`` rows, reads
+    them on a device of ``platform``: the Pallas kernel over live blocks
+    ("latent_kernel") on the TPU, where a block is whole tiles of its
+    layout, else the capacity-sized gathered view ("latent_gather")."""
+    from repro.kernels.decode_attention.latent import latent_whole_tiles
+
+    fits = latent_whole_tiles(kv_lora_rank, block_size, dtype)
+    return "latent_kernel" if platform == "tpu" and fits else "latent_gather"
+
+
+def _paged_latent_gather(q_lat, q_rope, ckv, k_rope, block_table, lengths, *, scale):
+    """Absorbed decode over the gathered (B, T * block_size) latent view:
+    the contiguous path's exact arithmetic."""
+    return latent_decode_attention(
+        q_lat, q_rope, paged_kv_view(ckv, block_table),
+        paged_kv_view(k_rope, block_table), lengths, scale=scale,
+    ).astype(q_lat.dtype)
+
+
+def _paged_latent_kernel(q_lat, q_rope, ckv, k_rope, block_table, lengths, *,
+                         scale, interpret=False):
+    """The Pallas paged latent decode: reads only each lane's live blocks of
+    both arenas (``kernels/decode_attention/latent.py``). A lane whose
+    table starts at the NULL block holds no rows: it reads nothing and its
+    o_lat is zeros."""
+    from repro.kernels.decode_attention import paged_latent_decode
+
+    lengths = jnp.where(block_table[:, 0] == NULL_BLOCK, 0, lengths)
+    out = paged_latent_decode(q_lat[:, 0], q_rope[:, 0], ckv, k_rope,
+                              block_table, lengths, scale=scale,
+                              interpret=interpret)
+    return out[:, None]
+
+
+_PAGED_LATENT = {"latent_kernel": _paged_latent_kernel,
+                 "latent_gather": _paged_latent_gather}
+
+
+def paged_latent_attention(q_lat, q_rope, ckv, k_rope, block_table, lengths, *, scale):
+    """Absorbed MLA decode of each lane over its paged latent cache, read
+    as ``paged_latent_path`` says for the platform the program is lowered
+    for: off the TPU, always the gathered view. -> o_lat (B, 1, H, r) in
+    ``q_lat``'s dtype."""
+    tpu = paged_latent_path(ckv.shape[-1], ckv.dtype, ckv.shape[1], "tpu")
+    return jax.lax.platform_dependent(
+        q_lat, q_rope, ckv, k_rope, block_table, lengths,
+        default=lambda *a: _paged_latent_gather(*a, scale=scale),
+        tpu=lambda *a: _PAGED_LATENT[tpu](*a, scale=scale),
+    )
+
+
 def mla_apply(
     params: Dict,
     x: jax.Array,
@@ -550,14 +619,7 @@ def mla_apply(
             cache["k_rope"], k_rope[:, :, 0, :], idx, block_table=block_table
         ),
     }
-    if block_table is not None:
-        c_ckv = paged_kv_view(new_cache["ckv"], block_table)
-        c_rope = paged_kv_view(new_cache["k_rope"], block_table)
-    else:
-        c_ckv, c_rope = new_cache["ckv"], new_cache["k_rope"]
-    S = c_ckv.shape[1]
     length = decode_lengths(idx, B)
-    pos_mask = jnp.arange(S)[None, :] < length[:, None]
 
     if absorb:
         # q_nope absorbed through W_UK: scores in latent space, rank r_kv.
@@ -566,16 +628,24 @@ def mla_apply(
         w_uv = wkv_b[:, :, m.qk_nope_head_dim:]       # (r, H, v)
         q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, w_uk)  # (B,1,H,r)
         scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
-        s = (
-            jnp.einsum("bshr,btr->bhst", q_lat.astype(jnp.float32), c_ckv.astype(jnp.float32))
-            + jnp.einsum("bshk,btk->bhst", q_rope.astype(jnp.float32), c_rope.astype(jnp.float32))
-        ) * scale
-        s = jnp.where(pos_mask[:, None, None, :], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        o_lat = jnp.einsum("bhst,btr->bshr", p, c_ckv.astype(jnp.float32))
+        if block_table is not None:
+            o_lat = paged_latent_attention(
+                q_lat, q_rope, new_cache["ckv"], new_cache["k_rope"],
+                block_table, length, scale=scale)
+        else:
+            o_lat = latent_decode_attention(
+                q_lat, q_rope, new_cache["ckv"], new_cache["k_rope"], length,
+                scale=scale)
         out = jnp.einsum("bshr,rhk->bshk", o_lat.astype(x.dtype), w_uv)
     else:
         # Baseline: expand the whole latent cache to per-head K/V each step.
+        if block_table is not None:
+            c_ckv = paged_kv_view(new_cache["ckv"], block_table)
+            c_rope = paged_kv_view(new_cache["k_rope"], block_table)
+        else:
+            c_ckv, c_rope = new_cache["ckv"], new_cache["k_rope"]
+        S = c_ckv.shape[1]
+        pos_mask = jnp.arange(S)[None, :] < length[:, None]
         k_nope, v = _mla_expand_kv(params, c_ckv, cfg)
         H = cfg.n_heads
         k_rope_b = jnp.broadcast_to(

@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.attention import NULL_BLOCK, paged_decode_path
+from repro.models.attention import NULL_BLOCK, paged_decode_path, paged_latent_path
 from repro.models.layers import ParamSpec, is_paged_spec, slot_mask_select
 from repro.models.moe import moe_capacity
 from repro.obs import NULL_OBS, Observability, span
@@ -298,22 +298,27 @@ class ServeEngine:
             model, n_slots, max_len, block_size,
             0 if self.pool.manager is None else self.pool.manager.num_blocks,
         )
-        #: The decode tick's KV read, the ``kv_path`` of its span: for a
-        #: paged GQA model what ``paged_decode_path`` picks on the pool's
-        #: device, "latent_gather" for paged MLA (the gathered latent
-        #: view), "gather" for other paged models, "contiguous" unpaged.
+        #: The decode tick's KV read, the ``kv_path`` of its span: what
+        #: ``paged_decode_path`` picks on the pool's device for a paged
+        #: GQA model, and ``paged_latent_path`` for paged absorbed MLA
+        #: ("latent_gather" for MLA that expands the cache), "gather" for
+        #: other paged models, "contiguous" unpaged.
         cfg = model.cfg
         if not self.pool.paged:
             self._kv_path = "contiguous"
-        elif model.gqa_decode:
-            leaf = jax.tree.leaves(self.pool.caches)[0]
-            self._kv_path = paged_decode_path(
-                cfg.n_kv_heads, cfg.head_dim, cfg.dtype,
-                next(iter(leaf.devices())).platform)
-        elif cfg.mla is not None:
-            self._kv_path = "latent_gather"
         else:
-            self._kv_path = "gather"
+            leaf = jax.tree.leaves(self.pool.caches)[0]
+            platform = next(iter(leaf.devices())).platform
+            if model.gqa_decode:
+                self._kv_path = paged_decode_path(
+                    cfg.n_kv_heads, cfg.head_dim, cfg.dtype, platform)
+            elif cfg.mla is not None and cfg.mla_absorb:
+                self._kv_path = paged_latent_path(
+                    cfg.mla.kv_lora_rank, cfg.dtype, block_size, platform)
+            elif cfg.mla is not None:
+                self._kv_path = "latent_gather"
+            else:
+                self._kv_path = "gather"
         #: MoE: each step's held-expert rows (a device scalar) wait here
         #: until the next read of tokens carries them back.
         self._moe_pending: List[jax.Array] = []

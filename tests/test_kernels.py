@@ -275,3 +275,60 @@ def test_paged_flash_decode_ragged_gqa_sweep():
         ref = decode_ref(q, k, v, jnp.asarray(lengths))
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, err_msg=f"Hkv={Hkv}")
+
+
+# ---------------------------------------------------------------------------
+# paged latent decode (MLA's absorbed decode over paged latent arenas)
+# ---------------------------------------------------------------------------
+
+from repro.kernels.decode_attention import (  # noqa: E402
+    paged_latent_decode,
+    paged_latent_ref,
+)
+from repro.models.attention import latent_decode_attention  # noqa: E402
+
+LATENT_CASES = [
+    # S, H, r, rope, block_size
+    (416, 16, 512, 64, 16),   # Moonlight's widths: up to 4 groups of 128 rows
+    (208, 4, 128, 32, 8),     # a narrow rank, rope and block: groups of 64 rows
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", LATENT_CASES, ids=["moonlight", "narrow"])
+def test_paged_latent_decode_matches_oracle(case, dtype):
+    """Kernel vs ``mla_apply``'s own absorbed einsums over the gathered
+    views, in one ragged batch: a lane of length 0 on a NULL table, lengths
+    that are not multiples of a block or a group, one lane of several
+    groups and one of the whole capacity. Only live blocks are populated
+    in the arenas (garbage elsewhere, the sink included), so a read past a
+    table's live prefix shows up."""
+    S, H, r, rope, bs = case
+    group = PAGES_PER_GROUP * bs
+    lengths = np.array([0, 1, bs - 1, bs + 3, group - 5, group + 7,
+                        3 * group - 9, S], np.int32)
+    B = len(lengths)
+    scale = 1.0 / np.sqrt(192.0)
+    q_lat = _arr((B, H, r), dtype)
+    q_rope = _arr((B, H, rope), dtype)
+    ckv = _arr((B, S, r), dtype)
+    k_rope = _arr((B, S, rope), dtype)
+    c_arena, r_arena, tables = _scatter_to_arena(ckv, k_rope, lengths, bs)
+    assert not np.asarray(tables[0]).any()       # length 0: a NULL table
+    lengths = jnp.asarray(lengths)
+
+    ref = paged_latent_ref(q_lat, q_rope, c_arena, r_arena, tables, lengths,
+                           scale=scale)
+    out = paged_latent_decode(q_lat, q_rope, c_arena, r_arena, tables, lengths,
+                              scale=scale, interpret=True)
+    assert out.shape == (B, H, r) and out.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(out[0], np.float32), 0.0)
+    # bfloat16: the output's own rounding and the probabilities' on the MXU
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               atol=tol, rtol=tol)
+
+    # The gathered oracle is the contiguous path bit for bit on live lanes.
+    contig = latent_decode_attention(q_lat[:, None], q_rope[:, None], ckv, k_rope,
+                                     lengths, scale=scale)[:, 0]
+    np.testing.assert_array_equal(np.asarray(ref)[1:], np.asarray(contig)[1:])

@@ -18,6 +18,7 @@ program's weights and compare:
 """
 
 import dataclasses
+import functools
 import sys
 from pathlib import Path
 
@@ -125,6 +126,81 @@ def test_paged_prefill_and_decode_match_the_reference_logits():
         np.testing.assert_allclose(prog, want, atol=LOGIT_ATOL, rtol=0)
         # each served token is the program's own argmax at its position
         np.testing.assert_array_equal(prog.argmax(-1), toks)
+
+
+def test_paged_decode_through_the_latent_kernel_matches_the_reference_logits(monkeypatch):
+    """The same served logits with paged decode run by the interpreted
+    Pallas latent kernel (the TPU's path) in place of the gathered view,
+    over lanes of one and of two kernel groups (blocks of 8, 8 a group)."""
+    monkeypatch.setattr(attn, "paged_latent_attention", functools.partial(
+        attn._paged_latent_kernel, interpret=True))
+    cfg = _cfg()
+    model = build_model(cfg)
+    params = _params(model)
+    d = _dims(cfg)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, cfg.vocab_size, size=n).astype(np.int32) for n in (11, 70)]
+    _, served = _serve_capturing_logits(model, params, prompts, 9)
+    for prompt, (toks, prog) in zip(prompts, served.values()):
+        seq = np.concatenate([prompt, np.asarray(toks, np.int32)])
+        want = _ref_logits(d, params, seq)[len(prompt) - 1: len(seq) - 1]
+        np.testing.assert_allclose(prog, want, atol=LOGIT_ATOL, rtol=0)
+        np.testing.assert_array_equal(prog.argmax(-1), toks)
+
+
+@pytest.mark.parametrize("rank,dtype,block,platform,path", [
+    (512, jnp.bfloat16, 16, "tpu", "latent_kernel"),
+    (512, jnp.float32, 16, "tpu", "latent_kernel"),
+    (512, jnp.float8_e4m3fn, 16, "tpu", "latent_gather"),   # half a (32, 128) tile
+    (448, jnp.bfloat16, 16, "tpu", "latent_gather"),        # rank not whole lanes
+    (512, jnp.bfloat16, 8, "tpu", "latent_gather"),         # half a (16, 128) tile
+    (512, jnp.bfloat16, 16, "cpu", "latent_gather"),
+], ids=["bf16-tpu", "f32-tpu", "fp8-tpu", "rank448-tpu", "block8-tpu", "bf16-cpu"])
+def test_latent_path_is_chosen_by_platform_and_geometry(rank, dtype, block, platform, path):
+    assert attn.paged_latent_path(rank, dtype, block, platform) == path
+
+
+def test_paged_latent_decode_lowers_to_the_kernel_only_for_the_tpu(monkeypatch):
+    """``mla_apply``'s paged absorbed decode lowers to the Pallas kernel for
+    the TPU where a block is whole tiles (rank 128 in float32, blocks of
+    16), and to the gathered view for the CPU. Run with the interpreted
+    kernel, its output matches the gather's on live lanes, its cache
+    writes are the same, and a dead lane on a NULL table reads nothing."""
+    base = _cfg()
+    cfg = dataclasses.replace(base, mla=dataclasses.replace(base.mla, kv_lora_rank=128))
+    p = build_model(cfg).init(jax.random.PRNGKey(8))["stack"][0]["attn"]
+    m, B, T, bs = cfg.mla, 4, 24, 16
+    rng = np.random.default_rng(4)
+    cache = {"ckv": jnp.asarray(rng.normal(size=(B * T + 1, bs, m.kv_lora_rank)), jnp.float32),
+             "k_rope": jnp.asarray(rng.normal(size=(B * T + 1, bs, m.qk_rope_head_dim)),
+                                   jnp.float32)}
+    idx = jnp.asarray([0, 21, 8 * 16 + 5, T * bs - 1], jnp.int32)
+    table = np.asarray(rng.permutation(B * T) + 1, np.int32).reshape(B, T)
+    table[0] = attn.NULL_BLOCK                  # a dead lane writes to the sink
+    table = jnp.asarray(table)
+    x = jnp.asarray(rng.normal(size=(B, 1, cfg.d_model)), jnp.float32)
+
+    def apply():
+        return attn.mla_apply(p, x, cfg, positions=idx[:, None], cache=cache,
+                              cache_index=idx, absorb=True, block_table=table)
+
+    y_gather, c_gather = apply()
+    H = cfg.n_heads
+    traced = jax.jit(functools.partial(attn.paged_latent_attention, scale=0.1)).trace(
+        jnp.zeros((B, 1, H, m.kv_lora_rank)), jnp.zeros((B, 1, H, m.qk_rope_head_dim)),
+        cache["ckv"], cache["k_rope"], table, idx + 1)
+    for platform, kernel in ((jax.default_backend(), False), ("tpu", True)):
+        text = traced.lower(lowering_platforms=(platform,)).as_text()
+        assert ("tpu_custom_call" in text) == kernel, platform
+
+    monkeypatch.setattr(attn, "paged_latent_attention", functools.partial(
+        attn._paged_latent_kernel, interpret=True))
+    y_kernel, c_kernel = apply()
+    np.testing.assert_array_equal(np.asarray(y_kernel[0]), 0.0)
+    np.testing.assert_allclose(np.asarray(y_kernel[1:]), np.asarray(y_gather[1:]),
+                               atol=2e-5, rtol=2e-5)
+    for name in ("ckv", "k_rope"):
+        np.testing.assert_array_equal(np.asarray(c_kernel[name]), np.asarray(c_gather[name]))
 
 
 def test_engine_counts_held_expert_rows():

@@ -3,8 +3,9 @@
 Interpret mode cannot see the chip compiler's block-tiling rule (the last
 two block dims divisible by (8, 128) or equal to the array's), so each
 kernel is lowered and compiled here at the widths the chip smoke runs:
-smollm-135m's 9 query heads, 3 KV heads and head_dim 64 in bfloat16, and
-a Mamba2-sized SSD scan. Nothing executes; a compile takes about a second.
+smollm-135m's 9 query heads, 3 KV heads and head_dim 64 in bfloat16, the
+paged decodes at the chat cells' geometries, and a Mamba2-sized SSD scan.
+Nothing executes; a compile takes about a second.
 
 The topology is described inside a module fixture, never at import, so
 that every test worker collects the same tests and only the worker that
@@ -18,7 +19,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.decode_attention import flash_decode, paged_flash_decode
+from repro.kernels.decode_attention import (flash_decode, paged_flash_decode,
+                                            paged_latent_decode)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import rmsnorm
 from repro.kernels.ssd_scan import ssd_scan
@@ -89,6 +91,19 @@ def _paged_flash_decode_chat(spec):
     )
 
 
+def _paged_latent_decode_moonlight(spec):
+    # Moonlight chat's serving geometry: 16 heads over a latent rank of
+    # 512 and rope keys of 64, 32 slots of 512 table slots of 16 rows over
+    # a 5,121-row arena.
+    slots, table, block, rows, h, r, rope = 32, 512, 16, 5121, 16, 512, 64
+    return (
+        lambda *a: paged_latent_decode(*a, scale=192 ** -0.5),
+        [spec((slots, h, r)), spec((slots, h, rope)), spec((rows, block, r)),
+         spec((rows, block, rope)), spec((slots, table), jnp.int32),
+         spec((slots,), jnp.int32)],
+    )
+
+
 def _ssd_scan(spec):
     # Mamba2-130m-like mixer: 24 heads of 64, d_state 128, one group.
     return (
@@ -106,9 +121,11 @@ def _rmsnorm(spec):
 @pytest.mark.parametrize(
     "build",
     [_flash_attention, _flash_decode, _paged_flash_decode,
-     _paged_flash_decode_chat, _ssd_scan, _rmsnorm],
+     _paged_flash_decode_chat, _paged_latent_decode_moonlight, _ssd_scan,
+     _rmsnorm],
     ids=["flash_attention", "flash_decode", "paged_flash_decode",
-         "paged_flash_decode_chat", "ssd_scan", "rmsnorm"],
+         "paged_flash_decode_chat", "paged_latent_decode_moonlight",
+         "ssd_scan", "rmsnorm"],
 )
 def test_kernel_compiles_for_v5e(one_chip, build):
     def spec(shape, dtype=jnp.bfloat16):
